@@ -60,7 +60,7 @@ def _nearest_r_rows(rng, smoke):
     ns_r = jnp.asarray(rng.integers(1, r_max + 1, (B, K)).astype(np.int32))
     pallas = lambda a, n, r: window_join(  # noqa: E731
         a, n, r, max_sep=max_sep, r_max=r_max,
-        use_pallas=True, interpret=True, block_l=32, block_k=32)
+        use_pallas=True, interpret=True, block_l=128, block_k=128)
     v, lo, hi = (np.asarray(x) for x in pallas(a, ns, ns_r))
     wv, wlo, whi = (np.asarray(x) for x in jit_ref(a, ns, ns_r))
     ok = int(np.array_equal(v, wv) and np.array_equal(lo[wv], wlo[wv])

@@ -28,6 +28,9 @@ scale used in EXPERIMENTS.md; --smoke is the tiny-corpus CI invocation.
 ``--json [PATH]`` writes the serve + churn reports (cache hit rates,
 cold/warm drain latencies) to PATH (default BENCH_serve.json) so the
 perf trajectory is tracked across PRs.
+
+Compiled executables persist in JAX's compilation cache: the directory
+``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
@@ -35,6 +38,10 @@ from __future__ import annotations
 import argparse
 import json
 import platform
+import sys
+from pathlib import Path
+
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -48,6 +55,7 @@ def main() -> None:
                     help="write serve+churn reports as JSON (default %(const)s)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    cache = use_compile_cache(Path(__file__).resolve().parents[1])
 
     rows: list[tuple] = []
     reports: dict = {}
@@ -115,6 +123,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
+    print(cache.line(), file=sys.stderr)
 
     if args.json:
         payload = {

@@ -10,8 +10,8 @@ Key re-design vs the CPU engine (DESIGN.md §3):
   SENTINEL). K and L are *static* — the compiled step is the response-time
   guarantee;
 * Equalize == sorted intersection: key list 0 is the anchor stream; lists
-  1..K-1 are joined via vectorized membership (searchsorted on CPU/GPU,
-  the Pallas intersect kernel on TPU);
+  1..K-1 are joined via vectorized searchsorted membership on every
+  backend (the QT1 step has no Pallas path);
 * the index is document-sharded over the `model` mesh axis (each shard
   holds a doc range of every posting list); queries are batch-sharded over
   `pod`/`data`. Per-shard top-k results are all-gathered (k entries per
@@ -32,8 +32,6 @@ from repro.core.index_builder import ProximityIndex
 from repro.core.query import qt2_plan, qt34_plan, qt5_plan, select_fst_keys
 from repro.kernels.common import SENTINEL
 from repro.kernels.nearest_r import window_join
-
-from repro.kernels.common import shard_map_compat as _shard_map
 
 NEG_INF = jnp.float32(-1e30)
 
@@ -79,7 +77,9 @@ def qt1_score(valid, lo, hi, idf_sum, span_adjust):
 
 
 def qt1_topk(score, g_anchor, lo, hi, k: int):
-    top_s, top_i = jax.lax.top_k(score, k)
+    # top_k is an upper bound on results: a row shorter than k returns
+    # all of its lanes (invalid ones carry NEG_INF and decode drops them)
+    top_s, top_i = jax.lax.top_k(score, min(k, score.shape[-1]))
     take = lambda x: jnp.take_along_axis(x, top_i, axis=1)
     return top_s, take(g_anchor), take(lo), take(hi)
 
@@ -179,7 +179,7 @@ def _nearest_r_multi(b_rows, centers, max_sep: int, r, r_max: int):
 
 
 def qt34_join(a_g, ns_g, ns_r, max_sep: int, r_max: int,
-              use_pallas: bool = False):
+              use_pallas: bool = False, interpret: bool = False):
     """Ordinary-window join (QT3/QT4, DESIGN.md §13): the anchor lemma's
     ordinary posting row against the other lemmas' ordinary rows — for
     each anchor posting, every other row must hold r distinct positions
@@ -197,11 +197,11 @@ def qt34_join(a_g, ns_g, ns_r, max_sep: int, r_max: int,
     to the historical per-key argsort loop over ``_nearest_r_multi``
     (kept above as the documented device twin and test oracle)."""
     return window_join(a_g, ns_g, ns_r, max_sep=max_sep, r_max=r_max,
-                       use_pallas=use_pallas)
+                       use_pallas=use_pallas, interpret=interpret)
 
 
 def qt5_join(a_g, ns_g, ns_r, st_cnt, st_ext, st_r, max_sep: int, r_max: int,
-             use_pallas: bool = False):
+             use_pallas: bool = False, interpret: bool = False):
     """Join the QT5 anchor (rarest non-stop lemma) posting row against
     the other non-stop rows (the ordinary-window join of
     :func:`qt34_join`) and the per-(anchor, stop-lemma) NSW aggregate
@@ -212,14 +212,16 @@ def qt5_join(a_g, ns_g, ns_r, st_cnt, st_ext, st_r, max_sep: int, r_max: int,
     constraints fold into the same fused ``window_join`` pass (Pallas:
     into the same kernel), preserving the qt34/qt5 step sharing."""
     return window_join(a_g, ns_g, ns_r, st_cnt, st_ext, st_r,
-                       max_sep=max_sep, r_max=r_max, use_pallas=use_pallas)
+                       max_sep=max_sep, r_max=r_max, use_pallas=use_pallas,
+                       interpret=interpret)
 
 
 # --------------------------------------------------------------------------
 # sharded serve step
 # --------------------------------------------------------------------------
-def make_qt1_serve_step(mesh, top_k: int = 16, use_pallas: bool = False):
-    """Build the jitted, mesh-sharded QT1 serve step.
+def make_qt1_serve_step(mesh, top_k: int = 16):
+    """Build the jitted, mesh-sharded QT1 serve step (lax searchsorted
+    membership join; no Pallas kernel).
 
     Sharding: batch over pod+data axes, posting length (doc ranges) over
     model. The all-gather moves only K' = top_k entries per shard."""
@@ -240,11 +242,12 @@ def make_qt1_serve_step(mesh, top_k: int = 16, use_pallas: bool = False):
     batch_spec = P(batch_axes, None, "model")
     vec_spec = P(batch_axes)
     out_spec = P(batch_axes, None)
-    step = _shard_map(
+    step = jax.shard_map(
         local_step,
-        mesh,
+        mesh=mesh,
         in_specs=(batch_spec, batch_spec, batch_spec, vec_spec, vec_spec),
         out_specs=(out_spec, out_spec, out_spec, out_spec),
+        check_vma=False,
     )
     in_shardings = (
         NamedSharding(mesh, batch_spec),
@@ -301,11 +304,12 @@ def make_qt1_serve_step_compressed(mesh, top_k: int = 16, delta_g: bool = True):
     base_spec = batch_spec if delta_g else P(batch_axes, None, None)
     vec_spec = P(batch_axes)
     out_spec = P(batch_axes, None)
-    step = _shard_map(
+    step = jax.shard_map(
         local_step,
-        mesh,
+        mesh=mesh,
         in_specs=(base_spec, batch_spec, batch_spec, batch_spec, vec_spec, vec_spec),
         out_specs=(out_spec,) * 4,
+        check_vma=False,
     )
     shards = lambda spec: NamedSharding(mesh, spec)
     return jax.jit(
@@ -318,7 +322,7 @@ def make_qt1_serve_step_compressed(mesh, top_k: int = 16, delta_g: bool = True):
 
 def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
                        max_distance: int = 5, r_max: int = 4,
-                       use_pallas: bool = False):
+                       use_pallas: bool = False, interpret: bool = False):
     """Build the jitted, mesh-sharded QT2/QT3/QT4/QT5 serve step — the
     (w,v)-key / ordinary-window / NSW analogue of
     :func:`make_qt1_serve_step` (DESIGN.md §12-§13). One factory covers
@@ -339,8 +343,10 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
     The joins are payload-independent: compressed payloads are
     reconstructed elementwise and fuse into them. ``use_pallas``
     (qt34/qt5 only) routes the window join through the fused Pallas
-    nearest-r kernel — a TPU escape hatch; the default lax counting
-    join is the fast path on CPU hosts (DESIGN.md §16)."""
+    nearest-r kernel, compiled for the TPU; ``interpret=True`` runs it
+    in the Pallas interpreter instead, the only way it runs on a CPU
+    mesh. The default lax counting join runs everywhere (DESIGN.md
+    §16)."""
     assert qtype in ("qt2", "qt34", "qt5")
     assert payload in ("raw", "delta", "offsets")
     has_pod = "pod" in mesh.axis_names
@@ -393,7 +399,8 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
 
         def join_finish(a_g, ns_g, ns_r, idf_sum, span_adjust):
             valid, lo, hi = qt34_join(a_g, ns_g, ns_r, sep, r_max,
-                                      use_pallas=use_pallas)
+                                      use_pallas=use_pallas,
+                                      interpret=interpret)
             score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
             return finish(score, lo, lo, hi)
 
@@ -415,7 +422,8 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
 
         def join_finish(a_g, ns_g, ns_r, st_cnt, st_ext, st_r, idf_sum, span_adjust):
             valid, lo, hi = qt5_join(a_g, ns_g, ns_r, st_cnt, st_ext, st_r, sep,
-                                     r_max, use_pallas=use_pallas)
+                                     r_max, use_pallas=use_pallas,
+                                     interpret=interpret)
             score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
             return finish(score, lo, lo, hi)
 
@@ -444,7 +452,8 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
 
             in_specs = (arow, row, kvec, row, row, row, kvec, vec, vec)
 
-    step = _shard_map(local_step, mesh, in_specs=in_specs, out_specs=(out,) * 4)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                         out_specs=(out,) * 4, check_vma=False)
     shards = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
     return jax.jit(
         step,

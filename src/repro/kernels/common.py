@@ -4,7 +4,8 @@ All kernels follow the same contract:
 * written for TPU (pl.pallas_call + BlockSpec VMEM tiling, MXU/VPU-aligned
   tile shapes, scalar-prefetched dynamic block index maps);
 * validated on CPU with interpret=True against the pure-jnp oracles in
-  each kernel's ref.py.
+  each kernel's ref.py. Interpret mode is never chosen for the caller:
+  every kernel compiles for the TPU unless it is passed interpret=True.
 """
 
 from __future__ import annotations
@@ -16,11 +17,6 @@ import numpy as np
 # Sentinel for padded posting slots: larger than any real doc id / packed
 # (doc, pos) key, still valid int32.
 SENTINEL = np.int32(2**31 - 1)
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode: True unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
 
 
 def pad_to_multiple(x: jnp.ndarray, multiple: int, fill) -> jnp.ndarray:
@@ -35,21 +31,3 @@ def pad_to_multiple(x: jnp.ndarray, multiple: int, fill) -> jnp.ndarray:
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled (the
-    static checker cannot see through top_k / psum-reduced outputs).
-
-    Covers three API generations: top-level `jax.shard_map` with
-    `check_vma` (>= 0.5), top-level with the older `check_rep` spelling,
-    and `jax.experimental.shard_map` (0.4.x)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:  # promoted to top level but pre-rename
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)

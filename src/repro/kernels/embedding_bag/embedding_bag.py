@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import default_interpret
 
 DEFAULT_BLOCK_B = 128
 DEFAULT_BLOCK_V = 512
@@ -62,10 +61,8 @@ def embedding_bag_pallas(
     *,
     block_b: int = DEFAULT_BLOCK_B,
     block_v: int = DEFAULT_BLOCK_V,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = default_interpret()
     B, S = ids.shape
     V, D = table.shape
     grid = (B // block_b, V // block_v)

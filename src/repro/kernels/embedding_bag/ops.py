@@ -22,7 +22,7 @@ def embedding_bag(
     use_pallas: bool = False,
     block_b: int = DEFAULT_BLOCK_B,
     block_v: int = DEFAULT_BLOCK_V,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Bag-reduce embedding lookup. use_pallas routes through the MXU
     one-hot kernel (TPU hot path); default is the XLA gather reference,

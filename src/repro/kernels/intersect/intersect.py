@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import SENTINEL, cdiv, default_interpret, pad_to_multiple
+from repro.kernels.common import SENTINEL, cdiv, pad_to_multiple
 
 DEFAULT_BLOCK_A = 512
 DEFAULT_BLOCK_B = 1024
@@ -94,12 +94,10 @@ def intersect_pallas_compressed(
     block_a: int = DEFAULT_BLOCK_A,
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int = 1,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Compressed-stream variant: 2B deltas + 4B/64 bases ≈ 2.06 B/posting
     streamed from HBM vs 4 B/posting for raw int32."""
-    if interpret is None:
-        interpret = default_interpret()
     na_blocks = a_delta.shape[0] // block_a
     nb_blocks = b_delta.shape[0] // block_b
     kernel = functools.partial(_kernel_compressed, nb_blocks=nb_blocks)
@@ -139,13 +137,11 @@ def intersect_pallas(
     block_a: int = DEFAULT_BLOCK_A,
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int = 1,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """a, b: sorted int32, already padded to multiples of the block sizes
     with SENTINEL; starts: (num_a_blocks,) int32 — first B-block index each
     A-block may overlap. Returns (mask, idx) per element of a."""
-    if interpret is None:
-        interpret = default_interpret()
     na_blocks = a.shape[0] // block_a
     nb_blocks = b.shape[0] // block_b
     grid = (na_blocks, k_tiles)

@@ -79,7 +79,7 @@ def intersect_sorted_compressed(
     block_a: int = DEFAULT_BLOCK_A,
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Same contract as intersect_sorted (mask only) but the posting
     streams cross HBM as base+delta (2.06 B/posting)."""
@@ -112,7 +112,7 @@ def intersect_sorted(
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int | None = None,
     use_pallas: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Membership of each element of sorted `a` in sorted `b`.
 

@@ -11,9 +11,10 @@ Two production paths behind one signature:
   win over the argsort join on CPU and the baseline the kernel rows in
   ``benchmarks/kernel_bench.py`` quantify.
 - ``use_pallas=True``: the Pallas TPU kernel in ``nearest_r.py`` —
-  one blocked pass over all Kn rows with δ-presence bitmask scratch,
-  sparsest-first key order exploited via early-masked blocks
-  (interpret mode on CPU; see DESIGN.md §16).
+  one blocked pass over all Kn rows with a signed-distance bitmask per
+  anchor, sparsest-first key order exploited via early-masked blocks.
+  It compiles for the TPU; on any other backend the caller must pass
+  ``interpret=True`` (see DESIGN.md §16).
 
 Both reproduce ``ref.window_join_ref`` (and therefore the CPU engine's
 ``search._nearest_r``) bit-for-bit on valid lanes, including stable
@@ -100,8 +101,8 @@ def _fold_stops(valid, lo, hi, a_g, st_cnt, st_ext, st_r):
 
 def window_join(a_g, ns_g, ns_r, st_cnt=None, st_ext=None, st_r=None, *,
                 max_sep: int, r_max: int, use_pallas: bool = False,
-                interpret=None, block_l: int = 256, block_k: int = 512,
-                k_tiles=None):
+                interpret: bool = False, block_l: int = 1024,
+                block_k: int = 1024, k_tiles=None):
     """Fused ordinary-window + NSW join over all keys at once.
 
     a_g: (B, L) anchor rows; ns_g: (B, Kn, L) non-stop rows; ns_r:

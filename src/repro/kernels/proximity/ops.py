@@ -45,7 +45,7 @@ def proximity_join(
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int | None = None,
     use_pallas: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """For each a_i: (is there a b within d, min matched b, max matched b)."""
     a = jnp.asarray(a, jnp.int32)

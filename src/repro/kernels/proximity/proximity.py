@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import SENTINEL, default_interpret
+from repro.kernels.common import SENTINEL
 
 DEFAULT_BLOCK_A = 512
 DEFAULT_BLOCK_B = 1024
@@ -59,10 +59,8 @@ def proximity_pallas(
     block_a: int = DEFAULT_BLOCK_A,
     block_b: int = DEFAULT_BLOCK_B,
     k_tiles: int = 1,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
-    if interpret is None:
-        interpret = default_interpret()
     na_blocks = a.shape[0] // block_a
     nb_blocks = b.shape[0] // block_b
     kernel = functools.partial(_kernel, d=d)

@@ -4,17 +4,12 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x has no AxisType; meshes default to auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _mesh(shape: tuple, axes: tuple):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+def _mesh(shape: tuple, axes: tuple, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,6 +23,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_mesh(shape: tuple, axes: tuple):
     """Auto-typed mesh helper (tests / small runs)."""
     return _mesh(shape, axes)
+
+
+def mesh_on(devices) -> jax.sharding.Mesh:
+    """The serving mesh over exactly ``devices``: ("data", "model") =
+    (1, len(devices)), so doc shards map one per device."""
+    devices = list(devices)
+    return _mesh((1, len(devices)), ("data", "model"), devices=devices)
 
 
 def dp_axes_of(mesh) -> tuple:

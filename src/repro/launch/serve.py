@@ -19,6 +19,9 @@ config:
 
   PYTHONPATH=src python -m repro.launch.serve \
       --config results/tuned_serve_config.json
+
+Compiled executables persist in JAX's compilation cache: the directory
+``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
@@ -27,11 +30,13 @@ import argparse
 import dataclasses
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
 from repro.core.index_builder import build_index
 from repro.data.corpus import generate_corpus, sample_stop_queries
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.serving import SearchService, ServeConfig
 
@@ -101,6 +106,7 @@ def resolve_config(args) -> tuple[int, ServeConfig]:
 
 def main() -> None:
     args = build_parser().parse_args()
+    cache = use_compile_cache(Path(__file__).resolve().parents[3])
 
     table, lex = generate_corpus(args.n_docs, mean_doc_len=160, vocab_size=40_000, seed=1)
     max_distance, cfg = resolve_config(args)
@@ -133,6 +139,7 @@ def main() -> None:
             trace = service.write_trace(args.trace_out)
             print(f"wrote {len(trace['traceEvents'])} trace events to "
                   f"{args.trace_out} (open in https://ui.perfetto.dev)")
+        print(cache.line())
         return
 
     for q in queries:
@@ -162,6 +169,7 @@ def main() -> None:
         trace = service.write_trace(args.trace_out)
         print(f"wrote {len(trace['traceEvents'])} trace events to "
               f"{args.trace_out} (open in https://ui.perfetto.dev)")
+    print(cache.line())
 
 
 if __name__ == "__main__":

@@ -53,17 +53,16 @@ def sharded_embedding_lookup(table, ids, mesh, tp_axis="model", dp_axes=("data",
         out = _local_lookup(table_l, ids_l, rank, rows_per_shard)
         return jax.lax.psum(out, tp_axis)
 
-    from repro.kernels.common import shard_map_compat as shard_map
-
     ndim_ids = ids.ndim
     if ids_pspec is None:
         ids_pspec = P(dp_axes, *([None] * (ndim_ids - 1)))
     out_spec = P(*(tuple(ids_pspec) + (None,) * (ndim_ids + 1 - len(tuple(ids_pspec)))))
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(tp_axis, None), ids_pspec),
         out_specs=out_spec,
+        check_vma=False,
     )(table, ids)
 
 
@@ -85,23 +84,23 @@ def sharded_embedding_bag(table, ids, mesh, weights=None, tp_axis="model", dp_ax
             rows = rows * w_l[..., None].astype(rows.dtype)
         return jax.lax.psum(rows.sum(axis=-2), tp_axis)
 
-    from repro.kernels.common import shard_map_compat as shard_map
-
     nd = ids.ndim
     ids_spec = ids_pspec if ids_pspec is not None else P(dp_axes, *([None] * (nd - 1)))
     sp = tuple(ids_spec)
     sp = sp + (None,) * (nd - len(sp))
     out_spec = P(*(sp[: nd - 1] + (None,)))  # bag axis reduced away, D replicated
     if weights is None:
-        return shard_map(
+        return jax.shard_map(
             lambda t, i: body(t, i, None),
             mesh=mesh,
             in_specs=(P(tp_axis, None), ids_spec),
             out_specs=out_spec,
+            check_vma=False,
         )(table, ids)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(tp_axis, None), ids_spec, ids_spec),
         out_specs=out_spec,
+        check_vma=False,
     )(table, ids, weights)

@@ -82,18 +82,17 @@ def forward(cfg: EGNNConfig, params, feats, coords, src, dst, edge_mask, mesh=No
     def apply_layer(carry, p_l):
         h, x = carry
         if mesh is not None:
-            from repro.kernels.common import shard_map_compat as shard_map
-
             def body(p_loc, h_loc, x_loc, s_loc, d_loc, m_loc):
                 out = _egnn_layer(p_loc, h_loc, x_loc, s_loc, d_loc, m_loc, n_nodes)
                 return tuple(jax.lax.psum(o, edge_axes) for o in out)
 
             e_spec = P(edge_axes)
-            msg_agg, coord_agg, deg = shard_map(
+            msg_agg, coord_agg, deg = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(), P(), P(), e_spec, e_spec, e_spec),
                 out_specs=(P(), P(), P()),
+                check_vma=False,
             )(p_l, h, x, src, dst, edge_mask)
         else:
             msg_agg, coord_agg, deg = _egnn_layer(p_l, h, x, src, dst, edge_mask, n_nodes)

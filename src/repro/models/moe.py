@@ -141,13 +141,12 @@ def moe_block(p, x, *, cfg: MoEConfig, mesh, dp_axes: tuple, tp_axis: str = "mod
         "w_up": P(tp_axis),
         "w_down": P(tp_axis),
     }
-    from repro.kernels.common import shard_map_compat as shard_map
-
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(specs_in, P(dp_axes, None, None)),
         out_specs=(P(dp_axes, None, None), P()),
+        check_vma=False,
     )(p_in, x)
     return y, aux
 

@@ -29,25 +29,6 @@ from repro.models.layers import (
 from repro.models.moe import MoEConfig, init_moe, moe_block, moe_block_dense_ref
 
 
-@jax.custom_vjp
-def _opt_barrier(xs):
-    """optimization_barrier with an identity gradient — jax 0.4.x has no
-    differentiation rule for the primitive; the barrier only pins HLO
-    scheduling, so identity is the correct cotangent."""
-    return jax.lax.optimization_barrier(xs)
-
-
-def _opt_barrier_fwd(xs):
-    return _opt_barrier(xs), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (g,)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 @dataclass(frozen=True)
 class TransformerConfig:
     name: str
@@ -313,7 +294,7 @@ def lm_grads_microbatched(cfg: TransformerConfig, params, tokens, targets,
         casted = [leaf(x, s) for x, s in zip(flat_p, flat_s)]
         # the barrier pins the convert *before* the FSDP all-gather —
         # without it XLA sinks the bf16 cast past the gather and moves f32
-        casted = _opt_barrier(casted)
+        casted = jax.lax.optimization_barrier(casted)
         return td.unflatten(casted)
 
     def loss_fn(p, t, y):

@@ -120,26 +120,14 @@ def _payload_of_kind(kind: str) -> str:
     return _PAYLOAD_OF_KIND[kind.rsplit("_", 1)[-1] if "_" in kind else kind]
 
 
-def xla_cost_summary(compiled) -> dict | None:
-    """The interesting scalars of an XLA ``cost_analysis()`` — flops,
-    bytes accessed, transcendentals — tolerant of the list-vs-dict
-    return shape across jax versions. None when the backend does not
-    implement cost analysis."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return None
-    out = {}
-    for key in ("flops", "bytes accessed", "transcendentals",
-                "optimal_seconds"):
-        v = ca.get(key)
-        if v is not None:
-            out[key.replace(" ", "_")] = float(v)
-    return out
+def xla_cost_summary(compiled) -> dict:
+    """The interesting scalars of an XLA ``cost_analysis()`` dict —
+    flops, bytes accessed, transcendentals — under underscore names."""
+    ca = compiled.cost_analysis()
+    return {key.replace(" ", "_"): float(ca[key])
+            for key in ("flops", "bytes accessed", "transcendentals",
+                        "optimal_seconds")
+            if key in ca}
 
 
 class CompiledExecutor:
@@ -159,6 +147,9 @@ class CompiledExecutor:
                  tracer: Tracer | None = None, costs=None):
         self.mesh = mesh
         self.config = config
+        # Pallas kernels compile for a TPU mesh; on any other backend
+        # the interpreter is the only way they run
+        self.interpret = mesh.devices.flat[0].platform != "tpu"
         self.pack_cache = pack_cache
         self.compressed_cache = compressed_cache
         # optional PayloadCostModel (owned by the service): warm batch
@@ -172,11 +163,11 @@ class CompiledExecutor:
         # bounds how many shapes each one ever sees
         self._steps: dict[str, object] = {}
         self.executables: dict[tuple, int] = {}
-        # (kind, B, L) -> AOT-compiled executable (or the jit fallback
-        # when lowering failed); built on first execution of the triple
+        # (kind, B, L) -> AOT-compiled executable, built on first
+        # execution of the triple
         self._aot: dict[tuple, object] = {}
         self.compile_times: dict[tuple, float] = {}
-        self.cost_summaries: dict[tuple, dict | None] = {}
+        self.cost_summaries: dict[tuple, dict] = {}
         # (family, B, L) triples with measured run-time histograms
         self.measured_keys: set[tuple] = set()
         # delta-format eligibility on the cache-less compressed path is
@@ -208,7 +199,7 @@ class CompiledExecutor:
                 step = make_wv_serve_step(
                     self.mesh, qtype, top_k=cfg.top_k, payload=payload,
                     max_distance=max_distance, r_max=cfg.r_max,
-                    use_pallas=cfg.use_pallas,
+                    use_pallas=cfg.use_pallas, interpret=self.interpret,
                 )
             self._steps[kind] = step
         return step
@@ -260,7 +251,7 @@ class CompiledExecutor:
                                                  index.max_distance, args)
                 t_compile = time.perf_counter()
                 with self.tracer.span("dispatch", kind=kind):
-                    raw = self._call(key, fn, kind, index.max_distance, args)
+                    raw = fn(*args)
                 t_disp = time.perf_counter()
                 with self.tracer.span("execute", kind=kind, compile=first):
                     raw = jax.block_until_ready(raw)
@@ -283,7 +274,6 @@ class CompiledExecutor:
             self.executables[key] = self.executables.get(key, 0) + 1
             if not first:
                 # measured step cost = dispatch + device execute, run-only
-                # (first calls on the jit fallback would fold compile in)
                 self.metrics.observe(
                     f"serve.step.{step_family}.B{B_pad}.L{bucket}",
                     (t_exec - t_compile) * 1e6,
@@ -322,23 +312,16 @@ class CompiledExecutor:
         """The executable for one (kind, B, L) triple. First call per
         triple AOT-lowers and compiles the step (the ``compile`` phase)
         and captures its XLA cost_analysis summary; later calls return
-        the cached executable, so their step timings are pure run."""
+        the cached executable, so their step timings are pure run. A
+        step the compiler refuses raises here, in the compile phase."""
         fn = self._aot.get(key)
         if fn is not None:
             return fn, False
         step = self._step(kind, max_distance)
         with self.tracer.span("compile", kind=kind, B=key[1], L=key[2]):
             t0 = time.perf_counter()
-            try:
-                compiled = step.lower(*args).compile()
-                self.cost_summaries[key] = xla_cost_summary(compiled)
-                fn = compiled
-            except Exception:
-                # lowering is best-effort: fall back to the jit-cached
-                # step (compile then happens inside the first dispatch,
-                # so the split degrades gracefully instead of failing)
-                self.cost_summaries[key] = None
-                fn = step
+            fn = step.lower(*args).compile()
+            self.cost_summaries[key] = xla_cost_summary(fn)
             dt = time.perf_counter() - t0
         self._aot[key] = fn
         self.compile_times[key] = dt
@@ -346,19 +329,6 @@ class CompiledExecutor:
         self.metrics.observe(
             f"serve.compile.{kind}.B{key[1]}.L{key[2]}", dt * 1e6)
         return fn, True
-
-    def _call(self, key, fn, kind, max_distance, args):
-        try:
-            return fn(*args)
-        except (TypeError, ValueError):
-            if fn is self._steps.get(kind):
-                raise
-            # an AOT executable is stricter about input avals than jit;
-            # if a batch ever disagrees, demote the triple to the jit
-            # step permanently rather than failing the drain
-            step = self._step(kind, max_distance)
-            self._aot[key] = step
-            return step(*args)
 
     # -- measured-cost surface ---------------------------------------------
     def measured_step_us(self, family: str, B: int, L: int) -> float | None:

@@ -67,7 +67,9 @@ class ServeConfig:
     * ``buckets`` — the L-bucket ladder posting rows are padded to; one
       compiled executable exists per (step kind, B-bucket, L-bucket);
     * ``max_batch`` / ``top_k`` / ``doc_shards`` — batch cap, results
-      per query, model-axis doc shards;
+      per query (at most; a bucket shorter than ``top_k`` returns all
+      of its hits), model-axis doc shards — one per device of the
+      mesh's ``model`` axis;
     * ``compressed`` — serve the block-delta16 device payload
       (DESIGN.md §11-§12) with per-batch offsets fallback;
     * ``use_pack_cache`` / ``use_compressed_cache`` / ``cache_entries``
@@ -84,9 +86,10 @@ class ServeConfig:
       (step_family, L-bucket) from measured warm batch time
       (DESIGN.md §16); no effect on an uncompressed engine;
     * ``use_pallas`` — route the qt34/qt5 window join through the
-      fused Pallas nearest-r kernel (TPU; interpret-mode on CPU is for
-      validation only — the default lax counting join is the CPU fast
-      path, DESIGN.md §16);
+      fused Pallas nearest-r kernel: compiled for the chip on a TPU
+      mesh, run by the Pallas interpreter on any other mesh (tests
+      only); the default lax counting join runs everywhere
+      (DESIGN.md §16);
     * ``default_deadline_s`` — deadline attached to submits that don't
       pass one (None = no deadline);
     * ``admission`` — the §17 deadline control loop: ``submit()``
@@ -338,6 +341,13 @@ class SearchService:
                 "compressed serving requires max_distance <= 254 "
                 f"(got {self.index.max_distance})"
             )
+        if self.config.doc_shards != mesh.shape["model"]:
+            # each device of the model axis serves one doc-range shard;
+            # any other split would cut rows inside a shard's range and
+            # lose matches that straddle the cut
+            raise ValueError(
+                f"ServeConfig.doc_shards={self.config.doc_shards} must "
+                f"equal the mesh's model axis ({mesh.shape['model']})")
         self.mesh = mesh
         cfg = self.config
         # §15 observability tier: one registry + tracer per service,

@@ -105,7 +105,6 @@ def make_compressed_dp_train_step(loss_fn, opt_cfg, mesh, dp_axis="data",
     update. Returns step(params, opt_state, err_state, batch) ->
     (params, opt_state, err_state, metrics).
     """
-    from repro.kernels.common import shard_map_compat as shard_map
     from repro.train.optimizer import adamw_update
 
     def local_step(params, opt_state, err, batch):
@@ -125,11 +124,12 @@ def make_compressed_dp_train_step(loss_fn, opt_cfg, mesh, dp_axis="data",
         b_spec = jax.tree.map(
             lambda x: P(dp_axis, *([None] * (x.ndim - 1))), batch
         )
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(p_spec, o_spec, e_spec, b_spec),
             out_specs=(p_spec, o_spec, e_spec, {"loss": P(), "grad_norm": P()}),
+            check_vma=False,
         )(params, opt_state, err, batch)
 
     return jax.jit(step)

@@ -34,7 +34,8 @@ def test_intersect_vs_ref_shapes(na, nb, hi):
     a = _sorted_unique(rng, na, hi)
     b = _sorted_unique(rng, nb, hi)
     k = plan_k_int(a, b)
-    mask, idx = intersect_sorted(jnp.asarray(a), jnp.asarray(b), k_tiles=k)
+    mask, idx = intersect_sorted(jnp.asarray(a), jnp.asarray(b), k_tiles=k,
+                                 interpret=True)
     want = np.isin(a, b)
     np.testing.assert_array_equal(np.asarray(mask), want)
     # idx must point at the matching value in padded b
@@ -60,7 +61,7 @@ def test_intersect_property(xs, ys):
     a = np.unique(np.array(xs + [0], np.int32))
     b = np.unique(np.array(ys + [0], np.int32))
     mask, _ = intersect_sorted(jnp.asarray(a), jnp.asarray(b), block_a=8, block_b=16,
-                               k_tiles=plan_k_int(a, b, 8, 16))
+                               k_tiles=plan_k_int(a, b, 8, 16), interpret=True)
     np.testing.assert_array_equal(np.asarray(mask), np.isin(a, b))
 
 
@@ -68,7 +69,8 @@ def test_intersect_full_scan_default_k():
     rng = np.random.default_rng(3)
     a = _sorted_unique(rng, 600, 3000)
     b = _sorted_unique(rng, 900, 3000)
-    mask, _ = intersect_sorted(jnp.asarray(a), jnp.asarray(b))  # k_tiles=None
+    mask, _ = intersect_sorted(jnp.asarray(a), jnp.asarray(b),  # k_tiles=None
+                               interpret=True)
     np.testing.assert_array_equal(np.asarray(mask), np.isin(a, b))
 
 
@@ -80,7 +82,8 @@ def test_proximity_vs_ref(d, na, nb):
     a = _sorted_unique(rng, na, 8000)
     b = _sorted_unique(rng, nb, 8000)
     k = plan_k_prox(a, b, d)
-    mask, lo, hi = proximity_join(jnp.asarray(a), jnp.asarray(b), d, k_tiles=k)
+    mask, lo, hi = proximity_join(jnp.asarray(a), jnp.asarray(b), d, k_tiles=k,
+                                  interpret=True)
     rmask, rlo, rhi = proximity_join_ref(jnp.asarray(a), jnp.asarray(b), d)
     np.testing.assert_array_equal(np.asarray(mask), np.asarray(rmask))
     m = np.asarray(mask)
@@ -114,7 +117,7 @@ def test_embedding_bag_vs_ref(B, S, V, D, dtype):
     table = rng.normal(size=(V, D)).astype(np.float32)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
     out_k = embedding_bag(jnp.asarray(ids), jnp.asarray(table, dtype), use_pallas=True,
-                          block_b=32, block_v=128)
+                          block_b=32, block_v=128, interpret=True)
     out_r = embedding_bag_ref(jnp.asarray(ids), jnp.asarray(table, dtype))
     np.testing.assert_allclose(
         np.asarray(out_k, np.float32), np.asarray(out_r, np.float32), rtol=tol, atol=tol * 10
@@ -129,7 +132,8 @@ def test_embedding_bag_weights_and_mean():
     table = rng.normal(size=(V, D)).astype(np.float32)
     for combine in ("sum", "mean"):
         out_k = embedding_bag(jnp.asarray(ids), jnp.asarray(table), jnp.asarray(w),
-                              combine, use_pallas=True, block_b=8, block_v=16)
+                              combine, use_pallas=True, block_b=8, block_v=16,
+                              interpret=True)
         out_r = embedding_bag_ref(jnp.asarray(ids), jnp.asarray(table), jnp.asarray(w), combine)
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-5)
 
@@ -155,7 +159,8 @@ def test_intersect_compressed_vs_numpy(na, nb, hi):
     rng = np.random.default_rng(na + nb)
     a = _sorted_unique(rng, na, hi)
     b = _sorted_unique(rng, nb, hi)
-    mask = intersect_sorted_compressed(a, b, block_a=128, block_b=256)
+    mask = intersect_sorted_compressed(a, b, block_a=128, block_b=256,
+                                       interpret=True)
     np.testing.assert_array_equal(np.asarray(mask), np.isin(a, b))
 
 

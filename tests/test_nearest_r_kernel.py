@@ -149,13 +149,13 @@ def test_counting_qt5_stop_fold(seed, stride):
 def test_pallas_vs_ref(seed, stride):
     # Fixed shape/statics: one trace across examples (interpret is slow).
     rng = np.random.default_rng(seed)
-    b, kn, l, max_sep = 2, 2, 48, 4
+    b, kn, l, max_sep = 2, 2, 200, 4  # two 128-lane tiles per row
     a = _rows(rng, b, 1, l, stride, p_empty=0.0)[:, 0]
     ns = _rows(rng, b, kn, l, stride)
     ns_r = rng.integers(0, R_MAX + 1, (b, kn)).astype(np.int32)
     args = (jnp.asarray(a), jnp.asarray(ns), jnp.asarray(ns_r))
     got = window_join(*args, max_sep=max_sep, r_max=R_MAX,
-                      use_pallas=True, interpret=True, block_l=16, block_k=16)
+                      use_pallas=True, interpret=True, block_l=128, block_k=128)
     ref = window_join_ref(*args, max_sep=max_sep, r_max=R_MAX)
     _assert_same(got, ref)
 
@@ -171,7 +171,7 @@ def test_pallas_qt5_stop_fold(seed):
     args = (jnp.asarray(a), jnp.asarray(ns), jnp.asarray(ns_r),
             jnp.asarray(st_cnt), jnp.asarray(st_ext), jnp.asarray(st_r))
     got = window_join(*args, max_sep=max_sep, r_max=R_MAX,
-                      use_pallas=True, interpret=True, block_l=16, block_k=16)
+                      use_pallas=True, interpret=True, block_l=128, block_k=128)
     ref = window_join_ref(*args, max_sep=max_sep, r_max=R_MAX)
     cpu = _cpu_join(a, ns, ns_r, st_cnt, st_ext, st_r, max_sep=max_sep)
     _assert_same(got, ref)
@@ -184,11 +184,11 @@ def test_pallas_block_boundary_straddle():
     r nearest predecessors land in tile t and the successors in t+1.
     Exercised both with the safe full-row k_tiles bound and with the
     exact ``plan_k_tiles`` bound."""
-    l, block, max_sep = 32, 8, 6
-    ns = np.arange(2, 2 + 2 * l, 2, dtype=np.int32)[None, None, :]  # 2,4,..,64
-    # anchors at the values just past each 8-value tile edge (16, 32, 48)
+    l, block, max_sep = 512, 128, 6
+    ns = np.arange(2, 2 + 2 * l, 2, dtype=np.int32)[None, None, :]  # 2,..,1024
+    # anchors at the values just past each 128-value tile edge (256, 512, 768)
     a = np.full((1, l), SENTINEL, np.int32)
-    a[0, :6] = [15, 17, 31, 33, 47, 49]
+    a[0, :6] = [255, 257, 511, 513, 767, 769]
     ns_r = np.full((1, 1), 3, np.int32)
     args = (jnp.asarray(a), jnp.asarray(ns), jnp.asarray(ns_r))
     ref = window_join_ref(*args, max_sep=max_sep, r_max=R_MAX)
@@ -217,7 +217,7 @@ def test_tie_pred_before_succ():
             lambda: window_join_ref(*args, max_sep=5, r_max=R_MAX),
             lambda: window_join(*args, max_sep=5, r_max=R_MAX,
                                 use_pallas=True, interpret=True,
-                                block_l=8, block_k=8),
+                                block_l=128, block_k=128),
         ):
             valid, lo, hi = _np3(impl())
             assert valid[0, 0] and not valid[0, 1]

@@ -173,16 +173,23 @@ def test_scalar_fallback_rows(world):
             svc = _service(seg, mesh, serve_memtable=True, **over)
             svc.refresh()  # pulls live_view(): overlay becomes visible
             ref = seg.live_view()
+        elif over.get("doc_shards", 1) > 1:
+            svc = None  # a 1-device mesh cannot hold 2 shards: plan only
         else:
             svc = _service(idx, mesh, **over)
-        p = svc.explain(q)
+        if svc is None:
+            cfg = ServeConfig(**{"buckets": BUCKETS, "max_batch": 8,
+                                 "top_k": 256, **over})
+            p = planner.plan(q, idx, cfg)
+        else:
+            p = svc.explain(q)
         assert p.route == planner.ROUTE_SCALAR, (name, p)
         assert p.qtype == qtype, name
         assert p.fallback_reason == reason, (name, p.fallback_reason)
         assert p.bucket is None and p.payload is None
         assert p.est_step_cost is None  # no compiled-shape bound — the point
-        if over.get("doc_shards", 1) > 1:
-            continue  # plan-only: a 1-device mesh cannot execute 2 shards
+        if svc is None:
+            continue
         t = svc.submit(q)
         (r,) = svc.drain()
         assert r.path == "cpu" and r.plan == p, name
@@ -194,6 +201,15 @@ def test_scalar_fallback_rows(world):
     svc.submit([])
     (r,) = svc.drain()
     assert r.path == "empty" and r.results["doc"].size == 0
+
+
+def test_doc_shards_must_match_mesh(world):
+    """Each device of the mesh's model axis holds one doc-range shard:
+    a config that splits rows differently is refused at construction
+    instead of silently losing matches that straddle a shard cut."""
+    table, lex, idx, mesh, queries = world
+    with pytest.raises(ValueError, match="doc_shards"):
+        _service(idx, mesh, doc_shards=2)
 
 
 def test_every_matrix_reason_is_covered(world):
