@@ -1,0 +1,12 @@
+"""Roofline share of the QT1 serve step: the least time its work could
+take at the chip's peaks (``kernels/qt1.py``: bytes over HBM bandwidth)
+over the device time of the QT1 executables' runs in the trace."""
+
+
+def read(run):
+    dev = run.device
+    if dev is None or run.peaks is None or not dev.family_s.get("qt1"):
+        return None
+    work = run.kernel_work("qt1")
+    nbytes = sum(work(B, L, run.serve)["bytes"] for B, L in dev.family_runs["qt1"])
+    return 100.0 * nbytes / run.peaks["hbm_bytes_per_s"] / dev.family_s["qt1"]
