@@ -57,23 +57,26 @@ def qt1_join(key_g: jnp.ndarray, key_lo: jnp.ndarray, key_hi: jnp.ndarray):
     key_g/lo/hi: (B, K, L) int32. Returns (valid, lo, hi) each (B, L),
     aligned with the anchor list."""
     K = key_g.shape[1]
-    g0 = key_g[:, 0]
-    valid = g0 != SENTINEL
-    lo = key_lo[:, 0]
-    hi = key_hi[:, 0]
-    for k in range(1, K):
-        found, idx = _membership(g0, key_g[:, k])
-        valid &= found
-        lo_k = jnp.take_along_axis(key_lo[:, k], idx, axis=1)
-        hi_k = jnp.take_along_axis(key_hi[:, k], idx, axis=1)
-        lo = jnp.where(found, jnp.minimum(lo, lo_k), lo)
-        hi = jnp.where(found, jnp.maximum(hi, hi_k), hi)
+    with jax.named_scope("membership"):
+        g0 = key_g[:, 0]
+        valid = g0 != SENTINEL
+        lo = key_lo[:, 0]
+        hi = key_hi[:, 0]
+        for k in range(1, K):
+            found, idx = _membership(g0, key_g[:, k])
+            valid &= found
+            lo_k = jnp.take_along_axis(key_lo[:, k], idx, axis=1)
+            hi_k = jnp.take_along_axis(key_hi[:, k], idx, axis=1)
+            lo = jnp.where(found, jnp.minimum(lo, lo_k), lo)
+            hi = jnp.where(found, jnp.maximum(hi, hi_k), hi)
     return valid, lo, hi
 
 
 def qt1_score(valid, lo, hi, idf_sum, span_adjust):
-    span_excess = jnp.maximum((hi - lo) - span_adjust[:, None], 0)
-    return jnp.where(valid, idf_sum[:, None] / (1.0 + span_excess.astype(jnp.float32)), NEG_INF)
+    with jax.named_scope("score"):
+        span_excess = jnp.maximum((hi - lo) - span_adjust[:, None], 0)
+        return jnp.where(valid, idf_sum[:, None] / (1.0 + span_excess.astype(jnp.float32)),
+                         NEG_INF)
 
 
 def qt1_topk(score, g_anchor, lo, hi, k: int):
@@ -127,18 +130,19 @@ def qt2_join(wv_lo, wv_hi, n_keys, max_sep: int):
     such interval extends the fragment. Returns (valid, lo, hi) aligned
     with the anchor list."""
     K = wv_lo.shape[1]
-    a_lo = wv_lo[:, 0]
-    valid = a_lo != SENTINEL
-    lo = a_lo
-    hi = wv_hi[:, 0]
-    for k in range(1, K):
-        m, val, j = _nearest1(wv_lo[:, k], a_lo, max_sep)
-        b_hi = jnp.take_along_axis(wv_hi[:, k], j, axis=1)
-        active = (jnp.int32(k) < n_keys)[:, None]
-        valid &= m | ~active
-        upd = active & m
-        lo = jnp.where(upd, jnp.minimum(lo, val), lo)
-        hi = jnp.where(upd, jnp.maximum(hi, b_hi), hi)
+    with jax.named_scope("nearest"):
+        a_lo = wv_lo[:, 0]
+        valid = a_lo != SENTINEL
+        lo = a_lo
+        hi = wv_hi[:, 0]
+        for k in range(1, K):
+            m, val, j = _nearest1(wv_lo[:, k], a_lo, max_sep)
+            b_hi = jnp.take_along_axis(wv_hi[:, k], j, axis=1)
+            active = (jnp.int32(k) < n_keys)[:, None]
+            valid &= m | ~active
+            upd = active & m
+            lo = jnp.where(upd, jnp.minimum(lo, val), lo)
+            hi = jnp.where(upd, jnp.maximum(hi, b_hi), hi)
     return valid, lo, hi
 
 
@@ -219,6 +223,34 @@ def qt5_join(a_g, ns_g, ns_r, st_cnt, st_ext, st_r, max_sep: int, r_max: int,
 # --------------------------------------------------------------------------
 # sharded serve step
 # --------------------------------------------------------------------------
+# Every stage of a serve step runs under a ``jax.named_scope`` (the join
+# names itself: "membership", "nearest", "nearest_r"/"stops"; then
+# "score", "topk"), inside the step family's scope, so the compiled
+# program's ops carry "<family>/<stage>" in their metadata: names that
+# survive refactors and XLA's renumbering of fusions.
+def _scoped(family: str, fn):
+    """``fn`` with its body under the step family's named scope."""
+
+    @functools.wraps(fn)
+    def run(*args):
+        with jax.named_scope(family):
+            return fn(*args)
+
+    return run
+
+
+def _sharded_topk(score, g, lo, hi, top_k: int):
+    """Each shard's top-k, all-gathered over the doc-sharded ``model``
+    axis (k entries per shard) and reduced to the global top-k."""
+    with jax.named_scope("topk"):
+        s, g1, l1, h1 = qt1_topk(score, g, lo, hi, top_k)
+        s_all = jax.lax.all_gather(s, "model", axis=1, tiled=True)
+        g_all = jax.lax.all_gather(g1, "model", axis=1, tiled=True)
+        l_all = jax.lax.all_gather(l1, "model", axis=1, tiled=True)
+        h_all = jax.lax.all_gather(h1, "model", axis=1, tiled=True)
+        return qt1_topk(s_all, g_all, l_all, h_all, top_k)
+
+
 def make_qt1_serve_step(mesh, top_k: int = 16):
     """Build the jitted, mesh-sharded QT1 serve step (lax searchsorted
     membership join; no Pallas kernel).
@@ -231,19 +263,13 @@ def make_qt1_serve_step(mesh, top_k: int = 16):
     def local_step(key_g, key_lo, key_hi, idf_sum, span_adjust):
         valid, lo, hi = qt1_join(key_g, key_lo, key_hi)
         score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-        s, g, l, h = qt1_topk(score, key_g[:, 0], lo, hi, top_k)
-        # gather per-shard top-k across the doc-sharded axis
-        s_all = jax.lax.all_gather(s, "model", axis=1, tiled=True)
-        g_all = jax.lax.all_gather(g, "model", axis=1, tiled=True)
-        l_all = jax.lax.all_gather(l, "model", axis=1, tiled=True)
-        h_all = jax.lax.all_gather(h, "model", axis=1, tiled=True)
-        return qt1_topk(s_all, g_all, l_all, h_all, top_k)
+        return _sharded_topk(score, key_g[:, 0], lo, hi, top_k)
 
     batch_spec = P(batch_axes, None, "model")
     vec_spec = P(batch_axes)
     out_spec = P(batch_axes, None)
     step = jax.shard_map(
-        local_step,
+        _scoped("qt1", local_step),
         mesh=mesh,
         in_specs=(batch_spec, batch_spec, batch_spec, vec_spec, vec_spec),
         out_specs=(out_spec, out_spec, out_spec, out_spec),
@@ -279,25 +305,21 @@ def make_qt1_serve_step_compressed(mesh, top_k: int = 16, delta_g: bool = True):
     BLK = 64
 
     def local_step(key_base, key_delta, key_lo_off, key_hi_off, idf_sum, span_adjust):
-        if delta_g:
-            # (B,K,nb) int32 base + (B,K,L) uint16 deltas -> int32 keys
-            base = jnp.repeat(key_base, BLK, axis=2)
-            key_g = base + key_delta.astype(jnp.int32)
-        else:
-            key_g = key_delta
-        lo = key_g - key_lo_off.astype(jnp.int32)
-        hi = key_g + key_hi_off.astype(jnp.int32)
-        # SENTINEL-preservation: padding slots are marked by lo_off==255
-        pad = key_lo_off == 255
-        key_g = jnp.where(pad, SENTINEL, key_g)
+        with jax.named_scope("decompress"):
+            if delta_g:
+                # (B,K,nb) int32 base + (B,K,L) uint16 deltas -> int32 keys
+                base = jnp.repeat(key_base, BLK, axis=2)
+                key_g = base + key_delta.astype(jnp.int32)
+            else:
+                key_g = key_delta
+            lo = key_g - key_lo_off.astype(jnp.int32)
+            hi = key_g + key_hi_off.astype(jnp.int32)
+            # SENTINEL-preservation: padding slots are marked by lo_off==255
+            pad = key_lo_off == 255
+            key_g = jnp.where(pad, SENTINEL, key_g)
         valid, lo, hi = qt1_join(key_g, lo, hi)
         score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-        s, g, l, h = qt1_topk(score, key_g[:, 0], lo, hi, top_k)
-        s_all = jax.lax.all_gather(s, "model", axis=1, tiled=True)
-        g_all = jax.lax.all_gather(g, "model", axis=1, tiled=True)
-        l_all = jax.lax.all_gather(l, "model", axis=1, tiled=True)
-        h_all = jax.lax.all_gather(h, "model", axis=1, tiled=True)
-        return qt1_topk(s_all, g_all, l_all, h_all, top_k)
+        return _sharded_topk(score, key_g[:, 0], lo, hi, top_k)
 
     batch_spec = P(batch_axes, None, "model")
     # offsets-only: the dummy (B,K,1) base cannot shard its unit dim
@@ -305,7 +327,7 @@ def make_qt1_serve_step_compressed(mesh, top_k: int = 16, delta_g: bool = True):
     vec_spec = P(batch_axes)
     out_spec = P(batch_axes, None)
     step = jax.shard_map(
-        local_step,
+        _scoped("qt1", local_step),
         mesh=mesh,
         in_specs=(base_spec, batch_spec, batch_spec, batch_spec, vec_spec, vec_spec),
         out_specs=(out_spec,) * 4,
@@ -352,14 +374,6 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
     has_pod = "pod" in mesh.axis_names
     batch_axes = ("pod", "data") if has_pod else ("data",)
 
-    def finish(score, g, lo, hi):
-        s, g1, l1, h1 = qt1_topk(score, g, lo, hi, top_k)
-        s_all = jax.lax.all_gather(s, "model", axis=1, tiled=True)
-        g_all = jax.lax.all_gather(g1, "model", axis=1, tiled=True)
-        l_all = jax.lax.all_gather(l1, "model", axis=1, tiled=True)
-        h_all = jax.lax.all_gather(h1, "model", axis=1, tiled=True)
-        return qt1_topk(s_all, g_all, l_all, h_all, top_k)
-
     row = P(batch_axes, None, "model")  # (B, K, L) posting rows
     arow = P(batch_axes, "model")       # (B, L) anchor rows
     vec = P(batch_axes)                 # (B,) per-query scalars
@@ -373,24 +387,26 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
             valid, lo, hi = qt2_join(wv_lo, wv_hi, n_keys, sep)
             score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
             # the CPU engine derives the doc from lo, so lo doubles as g
-            return finish(score, lo, lo, hi)
+            return _sharded_topk(score, lo, lo, hi, top_k)
 
         if payload == "raw":
             local_step = join_finish
             in_specs = (row, row, vec, vec, vec)
         elif payload == "delta":
             def local_step(base, delta, width, n_keys, idf_sum, span_adjust):
-                lo = jnp.repeat(base, BLK, axis=2) + delta.astype(jnp.int32)
-                pad = width == 255
-                hi = jnp.where(pad, SENTINEL, lo + width.astype(jnp.int32))
-                lo = jnp.where(pad, SENTINEL, lo)
+                with jax.named_scope("decompress"):
+                    lo = jnp.repeat(base, BLK, axis=2) + delta.astype(jnp.int32)
+                    pad = width == 255
+                    hi = jnp.where(pad, SENTINEL, lo + width.astype(jnp.int32))
+                    lo = jnp.where(pad, SENTINEL, lo)
                 return join_finish(lo, hi, n_keys, idf_sum, span_adjust)
 
             in_specs = (row, row, row, vec, vec, vec)
         else:  # offsets
             def local_step(lo, width, n_keys, idf_sum, span_adjust):
-                pad = width == 255
-                hi = jnp.where(pad, SENTINEL, lo + width.astype(jnp.int32))
+                with jax.named_scope("decompress"):
+                    pad = width == 255
+                    hi = jnp.where(pad, SENTINEL, lo + width.astype(jnp.int32))
                 return join_finish(lo, hi, n_keys, idf_sum, span_adjust)
 
             in_specs = (row, row, vec, vec, vec)
@@ -402,7 +418,7 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
                                       use_pallas=use_pallas,
                                       interpret=interpret)
             score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-            return finish(score, lo, lo, hi)
+            return _sharded_topk(score, lo, lo, hi, top_k)
 
         if payload in ("raw", "offsets"):
             local_step = join_finish
@@ -410,10 +426,11 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
         else:  # delta
             def local_step(a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad,
                            ns_r, idf_sum, span_adjust):
-                a_g = jnp.repeat(a_base, BLK, axis=1) + a_delta.astype(jnp.int32)
-                a_g = jnp.where(a_pad == 1, SENTINEL, a_g)
-                ns_g = jnp.repeat(ns_base, BLK, axis=2) + ns_delta.astype(jnp.int32)
-                ns_g = jnp.where(ns_pad == 1, SENTINEL, ns_g)
+                with jax.named_scope("decompress"):
+                    a_g = jnp.repeat(a_base, BLK, axis=1) + a_delta.astype(jnp.int32)
+                    a_g = jnp.where(a_pad == 1, SENTINEL, a_g)
+                    ns_g = jnp.repeat(ns_base, BLK, axis=2) + ns_delta.astype(jnp.int32)
+                    ns_g = jnp.where(ns_pad == 1, SENTINEL, ns_g)
                 return join_finish(a_g, ns_g, ns_r, idf_sum, span_adjust)
 
             in_specs = (arow, arow, arow, row, row, row, kvec, vec, vec)
@@ -425,7 +442,7 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
                                      r_max, use_pallas=use_pallas,
                                      interpret=interpret)
             score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-            return finish(score, lo, lo, hi)
+            return _sharded_topk(score, lo, lo, hi, top_k)
 
         if payload == "raw":
             local_step = join_finish
@@ -433,12 +450,13 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
         elif payload == "delta":
             def local_step(a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad,
                            ns_r, st_cnt, st_eneg, st_epos, st_r, idf_sum, span_adjust):
-                a_g = jnp.repeat(a_base, BLK, axis=1) + a_delta.astype(jnp.int32)
-                a_g = jnp.where(a_pad == 1, SENTINEL, a_g)
-                ns_g = jnp.repeat(ns_base, BLK, axis=2) + ns_delta.astype(jnp.int32)
-                ns_g = jnp.where(ns_pad == 1, SENTINEL, ns_g)
-                cnt = st_cnt.astype(jnp.int32)
-                ext = st_epos.astype(jnp.int32) - st_eneg.astype(jnp.int32)
+                with jax.named_scope("decompress"):
+                    a_g = jnp.repeat(a_base, BLK, axis=1) + a_delta.astype(jnp.int32)
+                    a_g = jnp.where(a_pad == 1, SENTINEL, a_g)
+                    ns_g = jnp.repeat(ns_base, BLK, axis=2) + ns_delta.astype(jnp.int32)
+                    ns_g = jnp.where(ns_pad == 1, SENTINEL, ns_g)
+                    cnt = st_cnt.astype(jnp.int32)
+                    ext = st_epos.astype(jnp.int32) - st_eneg.astype(jnp.int32)
                 return join_finish(a_g, ns_g, ns_r, cnt, ext, st_r, idf_sum, span_adjust)
 
             in_specs = (arow, arow, arow, row, row, row, kvec, row, row, row,
@@ -446,14 +464,16 @@ def make_wv_serve_step(mesh, qtype: str, top_k: int = 16, payload: str = "raw",
         else:  # offsets
             def local_step(a_g, ns_g, ns_r, st_cnt, st_eneg, st_epos, st_r,
                            idf_sum, span_adjust):
-                cnt = st_cnt.astype(jnp.int32)
-                ext = st_epos.astype(jnp.int32) - st_eneg.astype(jnp.int32)
+                with jax.named_scope("decompress"):
+                    cnt = st_cnt.astype(jnp.int32)
+                    ext = st_epos.astype(jnp.int32) - st_eneg.astype(jnp.int32)
                 return join_finish(a_g, ns_g, ns_r, cnt, ext, st_r, idf_sum, span_adjust)
 
             in_specs = (arow, row, kvec, row, row, row, kvec, vec, vec)
 
-    step = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                         out_specs=(out,) * 4, check_vma=False)
+    step = jax.shard_map(_scoped(qtype, local_step), mesh=mesh,
+                         in_specs=in_specs, out_specs=(out,) * 4,
+                         check_vma=False)
     shards = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
     return jax.jit(
         step,
@@ -493,10 +513,11 @@ def _delta16_blocks(g):
 
 
 def compress_qt1_batch(batch: "QT1Batch", delta_g: bool = True):
-    """Pack a QT1Batch into the compressed device format (args for
-    make_qt1_serve_step_compressed). Raises if a 64-posting block's key
-    span exceeds uint16 (the serving packer then falls back to the
-    offsets-only format for that bucket)."""
+    """Pack a QT1Batch into the compressed format: host arrays, the args
+    of make_qt1_serve_step_compressed (the caller copies them to the
+    device). Raises if a 64-posting block's key span exceeds uint16 (the
+    serving packer then falls back to the offsets-only format for that
+    bucket)."""
     g = batch.key_g.astype(np.int64)
     B, K, L = g.shape
     # pads are marked by lo_off == 255 in the compressed format
@@ -506,24 +527,24 @@ def compress_qt1_batch(batch: "QT1Batch", delta_g: bool = True):
                       np.clip(batch.key_hi - g, 0, 254))
     if not delta_g:
         return (
-            jnp.zeros((B, K, 1), jnp.int32),
-            jnp.asarray(batch.key_g),
-            jnp.asarray(lo_off.astype(np.uint8)),
-            jnp.asarray(hi_off.astype(np.uint8)),
-            jnp.asarray(batch.idf_sum),
-            jnp.asarray(batch.span_adjust),
+            np.zeros((B, K, 1), np.int32),
+            batch.key_g,
+            lo_off.astype(np.uint8),
+            hi_off.astype(np.uint8),
+            batch.idf_sum,
+            batch.span_adjust,
         )
     assert L % BLK == 0
     base, delta, ok = _delta16_blocks(g)
     if not ok:
         raise ValueError("in-block key span exceeds uint16; use offsets format")
     return (
-        jnp.asarray(base),
-        jnp.asarray(delta),
-        jnp.asarray(lo_off.astype(np.uint8)),
-        jnp.asarray(hi_off.astype(np.uint8)),
-        jnp.asarray(batch.idf_sum),
-        jnp.asarray(batch.span_adjust),
+        base,
+        delta,
+        lo_off.astype(np.uint8),
+        hi_off.astype(np.uint8),
+        batch.idf_sum,
+        batch.span_adjust,
     )
 
 
@@ -539,14 +560,13 @@ class QT1Batch:
     span_adjust: np.ndarray  # (B,) f32 == len(query) - 1
     stride: int
 
+    def host_args(self):
+        """The step's arguments as host arrays, in step order."""
+        return (self.key_g, self.key_lo, self.key_hi, self.idf_sum,
+                self.span_adjust)
+
     def device_args(self):
-        return (
-            jnp.asarray(self.key_g),
-            jnp.asarray(self.key_lo),
-            jnp.asarray(self.key_hi),
-            jnp.asarray(self.idf_sum),
-            jnp.asarray(self.span_adjust),
-        )
+        return tuple(jnp.asarray(a) for a in self.host_args())
 
 
 def qt1_stride(index) -> int:
@@ -813,9 +833,12 @@ class QT2Batch:
     span_adjust: np.ndarray
     stride: int
 
+    def host_args(self):
+        return (self.wv_lo, self.wv_hi, self.n_keys, self.idf_sum,
+                self.span_adjust)
+
     def device_args(self):
-        return tuple(jnp.asarray(a) for a in (
-            self.wv_lo, self.wv_hi, self.n_keys, self.idf_sum, self.span_adjust))
+        return tuple(jnp.asarray(a) for a in self.host_args())
 
 
 @dataclass
@@ -830,10 +853,12 @@ class QT5Batch:
     span_adjust: np.ndarray
     stride: int
 
+    def host_args(self):
+        return (self.a_g, self.ns_g, self.ns_r, self.st_cnt, self.st_ext,
+                self.st_r, self.idf_sum, self.span_adjust)
+
     def device_args(self):
-        return tuple(jnp.asarray(a) for a in (
-            self.a_g, self.ns_g, self.ns_r, self.st_cnt, self.st_ext,
-            self.st_r, self.idf_sum, self.span_adjust))
+        return tuple(jnp.asarray(a) for a in self.host_args())
 
 
 @dataclass
@@ -845,9 +870,12 @@ class QT34Batch:
     span_adjust: np.ndarray
     stride: int
 
+    def host_args(self):
+        return (self.a_g, self.ns_g, self.ns_r, self.idf_sum,
+                self.span_adjust)
+
     def device_args(self):
-        return tuple(jnp.asarray(a) for a in (
-            self.a_g, self.ns_g, self.ns_r, self.idf_sum, self.span_adjust))
+        return tuple(jnp.asarray(a) for a in self.host_args())
 
 
 # the QT2 key ordering moved beside the other per-type plans in
@@ -1039,7 +1067,7 @@ def pack_qt34_batch(
 
 
 def compress_qt2_batch(batch: QT2Batch, delta_g: bool = True):
-    """QT2Batch -> compressed device args. Interval widths (hi - lo <=
+    """QT2Batch -> compressed step args (host arrays). Interval widths (hi - lo <=
     MaxDistance <= 254) ride as uint8 (255 marks padding); with delta_g
     the lo stream is block-delta16 coded. Raises on uint16 overflow (the
     engine then falls back to the offsets format)."""
@@ -1047,28 +1075,26 @@ def compress_qt2_batch(batch: QT2Batch, delta_g: bool = True):
     pad = lo == np.int64(SENTINEL)
     width = np.where(pad, 255,
                      np.clip(batch.wv_hi.astype(np.int64) - lo, 0, 254)).astype(np.uint8)
-    tail = (jnp.asarray(width), jnp.asarray(batch.n_keys),
-            jnp.asarray(batch.idf_sum), jnp.asarray(batch.span_adjust))
+    tail = (width, batch.n_keys, batch.idf_sum, batch.span_adjust)
     if not delta_g:
-        return (jnp.asarray(batch.wv_lo),) + tail
+        return (batch.wv_lo,) + tail
     assert lo.shape[-1] % BLK == 0
     base, delta, ok = _delta16_blocks(lo)
     if not ok:
         raise ValueError("in-block key span exceeds uint16; use offsets format")
-    return (jnp.asarray(base), jnp.asarray(delta)) + tail
+    return (base, delta) + tail
 
 
 def compress_qt34_batch(batch: QT34Batch, delta_g: bool = True):
-    """QT34Batch -> compressed device args: with delta_g the anchor and
+    """QT34Batch -> compressed step args (host arrays): with delta_g the anchor and
     other ordinary streams are block-delta16 coded behind uint8 pad
     masks (4 B/posting class); without it the int32 rows ship as-is
     (the "offsets" format — QT3/QT4 has no uint8 side channels, so the
     fallback is simply uncompressed). Raises on uint16 overflow (the
     engine then falls back to the offsets format)."""
-    tail = (jnp.asarray(batch.ns_r), jnp.asarray(batch.idf_sum),
-            jnp.asarray(batch.span_adjust))
+    tail = (batch.ns_r, batch.idf_sum, batch.span_adjust)
     if not delta_g:
-        return (jnp.asarray(batch.a_g), jnp.asarray(batch.ns_g)) + tail
+        return (batch.a_g, batch.ns_g) + tail
     a = batch.a_g.astype(np.int64)
     ns = batch.ns_g.astype(np.int64)
     assert a.shape[-1] % BLK == 0
@@ -1078,23 +1104,21 @@ def compress_qt34_batch(batch: QT34Batch, delta_g: bool = True):
         raise ValueError("in-block key span exceeds uint16; use offsets format")
     a_pad = (a == np.int64(SENTINEL)).astype(np.uint8)
     ns_pad = (ns == np.int64(SENTINEL)).astype(np.uint8)
-    return (jnp.asarray(a_base), jnp.asarray(a_delta), jnp.asarray(a_pad),
-            jnp.asarray(ns_base), jnp.asarray(ns_delta), jnp.asarray(ns_pad)) + tail
+    return (a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad) + tail
 
 
 def compress_qt5_batch(batch: QT5Batch, delta_g: bool = True):
-    """QT5Batch -> compressed device args: uint8 NSW counts (clipped at
+    """QT5Batch -> compressed step args (host arrays): uint8 NSW counts (clipped at
     255 — multiplicities are far smaller) and split-sign uint8 nearest
     offsets (|ext| <= MaxDistance <= 254); with delta_g the anchor and
     non-stop streams are block-delta16 coded behind uint8 pad masks."""
     cnt8 = np.clip(batch.st_cnt, 0, 255).astype(np.uint8)
     eneg = np.clip(-np.minimum(batch.st_ext, 0), 0, 255).astype(np.uint8)
     epos = np.clip(np.maximum(batch.st_ext, 0), 0, 255).astype(np.uint8)
-    tail = (jnp.asarray(batch.ns_r), jnp.asarray(cnt8), jnp.asarray(eneg),
-            jnp.asarray(epos), jnp.asarray(batch.st_r),
-            jnp.asarray(batch.idf_sum), jnp.asarray(batch.span_adjust))
+    tail = (batch.ns_r, cnt8, eneg, epos, batch.st_r, batch.idf_sum,
+            batch.span_adjust)
     if not delta_g:
-        return (jnp.asarray(batch.a_g), jnp.asarray(batch.ns_g)) + tail
+        return (batch.a_g, batch.ns_g) + tail
     a = batch.a_g.astype(np.int64)
     ns = batch.ns_g.astype(np.int64)
     assert a.shape[-1] % BLK == 0
@@ -1104,8 +1128,7 @@ def compress_qt5_batch(batch: QT5Batch, delta_g: bool = True):
         raise ValueError("in-block key span exceeds uint16; use offsets format")
     a_pad = (a == np.int64(SENTINEL)).astype(np.uint8)
     ns_pad = (ns == np.int64(SENTINEL)).astype(np.uint8)
-    return (jnp.asarray(a_base), jnp.asarray(a_delta), jnp.asarray(a_pad),
-            jnp.asarray(ns_base), jnp.asarray(ns_delta), jnp.asarray(ns_pad)) + tail
+    return (a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad) + tail
 
 
 # --------------------------------------------------------------------------
@@ -1163,9 +1186,9 @@ def compress_nsw_rows(rows):
 # --------------------------------------------------------------------------
 def assemble_qt1_compressed(index, queries, L, K=2, doc_shards=1,
                             ccache=None, cache=None, plans=None):
-    """Build compressed QT1 device args from per-key *cached* compressed
-    rows: warm drains become B*K row copies instead of an O(B·K·L) host
-    re-encode. Returns (kind, args, batch_stub) with kind "delta" or
+    """Build compressed QT1 step args (host arrays) from per-key
+    *cached* compressed rows: warm drains become B*K row copies instead
+    of an O(B·K·L) host re-encode. Returns (kind, args, batch_stub) with kind "delta" or
     "offsets" (chosen per batch: offsets when any key's in-block span
     overflows uint16 or the bucket is block/shard-misaligned)."""
     B = len(queries)
@@ -1199,8 +1222,7 @@ def assemble_qt1_compressed(index, queries, L, K=2, doc_shards=1,
             idf_sum[qi] = sum(lex.idf(l) for l in q)
         ents[qi] = row_ents
     stub = QT1Batch(None, None, None, idf_sum, span_adj, stride)
-    tail = (jnp.asarray(lo_off), jnp.asarray(hi_off),
-            jnp.asarray(idf_sum), jnp.asarray(span_adj))
+    tail = (lo_off, hi_off, idf_sum, span_adj)
     if delta_fmt:
         key_base = np.zeros((B, K, L // BLK), np.int32)
         key_delta = np.zeros((B, K, L), np.uint16)
@@ -1211,7 +1233,7 @@ def assemble_qt1_compressed(index, queries, L, K=2, doc_shards=1,
                 if present:
                     key_base[qi, ki] = base
                     key_delta[qi, ki] = delta
-        return "delta", (jnp.asarray(key_base), jnp.asarray(key_delta)) + tail, stub
+        return "delta", (key_base, key_delta) + tail, stub
     key_g = np.full((B, K, L), SENTINEL, np.int32)
     for qi, row_ents in enumerate(ents):
         if row_ents is None:
@@ -1225,13 +1247,13 @@ def assemble_qt1_compressed(index, queries, L, K=2, doc_shards=1,
                 g_row, _, _, pres = pack_fst_key_rows(index, key, L, doc_shards, stride)
             if pres:
                 key_g[qi, ki] = g_row
-    args = (jnp.zeros((B, K, 1), jnp.int32), jnp.asarray(key_g)) + tail
+    args = (np.zeros((B, K, 1), np.int32), key_g) + tail
     return "offsets", args, stub
 
 
 def assemble_qt2_compressed(index, queries, L, K=3, doc_shards=1,
                             ccache=None, cache=None, plans=None):
-    """Compressed QT2 device args from per-key cached rows (kind "wv_c").
+    """Compressed QT2 step args (host arrays) from per-key cached rows (kind "wv_c").
     Returns (kind, args, batch_stub), kind "qt2_delta" / "qt2_offsets"."""
     B = len(queries)
     stride = qt1_stride(index)
@@ -1263,8 +1285,7 @@ def assemble_qt2_compressed(index, queries, L, K=3, doc_shards=1,
             idf_sum[qi] = sum(lex.idf(l) for l in q)
         ents[qi] = row_ents
     stub = QT2Batch(None, None, n_keys, idf_sum, span_adj, stride)
-    tail = (jnp.asarray(width), jnp.asarray(n_keys),
-            jnp.asarray(idf_sum), jnp.asarray(span_adj))
+    tail = (width, n_keys, idf_sum, span_adj)
     if delta_fmt:
         lo_base = np.zeros((B, K, L // BLK), np.int32)
         lo_delta = np.zeros((B, K, L), np.uint16)
@@ -1275,7 +1296,7 @@ def assemble_qt2_compressed(index, queries, L, K=3, doc_shards=1,
                 if present:
                     lo_base[qi, ki] = base
                     lo_delta[qi, ki] = delta
-        return "qt2_delta", (jnp.asarray(lo_base), jnp.asarray(lo_delta)) + tail, stub
+        return "qt2_delta", (lo_base, lo_delta) + tail, stub
     wv_lo = np.full((B, K, L), SENTINEL, np.int32)
     for qi, row_ents in enumerate(ents):
         if row_ents is None:
@@ -1289,12 +1310,12 @@ def assemble_qt2_compressed(index, queries, L, K=3, doc_shards=1,
                 lo_row, _, pres = pack_wv_key_rows(index, key, L, doc_shards, stride)
             if pres:
                 wv_lo[qi, ki] = lo_row
-    return "qt2_offsets", (jnp.asarray(wv_lo),) + tail, stub
+    return "qt2_offsets", (wv_lo,) + tail, stub
 
 
 def assemble_qt34_compressed(index, queries, L, Kn=4, doc_shards=1,
                              ccache=None, cache=None, plans=None):
-    """Compressed QT3/QT4 device args from per-key cached rows (kind
+    """Compressed QT3/QT4 step args (host arrays) from per-key cached rows (kind
     "ord_c" — shared with the QT5 anchor/non-stop streams, so a key hot
     on either path warms both). Returns (kind, args, batch_stub), kind
     "qt34_delta" / "qt34_offsets"."""
@@ -1334,7 +1355,7 @@ def assemble_qt34_compressed(index, queries, L, Kn=4, doc_shards=1,
         ns_ents[qi] = row_ents
         idf_sum[qi] = sum(lex.idf(l) for l in q)
     stub = QT34Batch(None, None, ns_r, idf_sum, span_adj, stride)
-    tail = (jnp.asarray(ns_r), jnp.asarray(idf_sum), jnp.asarray(span_adj))
+    tail = (ns_r, idf_sum, span_adj)
     if delta_fmt:
         nb = L // BLK
         a_base = np.zeros((B, nb), np.int32)
@@ -1349,9 +1370,7 @@ def assemble_qt34_compressed(index, queries, L, Kn=4, doc_shards=1,
                 if pr2:
                     ns_base[qi, ki] = b2
                     ns_delta[qi, ki] = d2
-        args = (jnp.asarray(a_base), jnp.asarray(a_delta), jnp.asarray(a_pad),
-                jnp.asarray(ns_base), jnp.asarray(ns_delta),
-                jnp.asarray(ns_pad)) + tail
+        args = (a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad) + tail
         return "qt34_delta", args, stub
 
     def raw_row(lemma):
@@ -1371,12 +1390,12 @@ def assemble_qt34_compressed(index, queries, L, Kn=4, doc_shards=1,
                 g_row, pres = raw_row(lemma)
                 if pres:
                     ns_g[qi, ki] = g_row
-    return "qt34_offsets", (jnp.asarray(a_g), jnp.asarray(ns_g)) + tail, stub
+    return "qt34_offsets", (a_g, ns_g) + tail, stub
 
 
 def assemble_qt5_compressed(index, queries, L, Kn=3, Ks=3, doc_shards=1,
                             ccache=None, cache=None, plans=None):
-    """Compressed QT5 device args from per-key cached rows (kinds "ord_c"
+    """Compressed QT5 step args (host arrays) from per-key cached rows (kinds "ord_c"
     for anchor/non-stop streams, "nsw_c" for the uint8 NSW aggregates).
     Returns (kind, args, batch_stub), kind "qt5_delta" / "qt5_offsets"."""
     B = len(queries)
@@ -1429,9 +1448,7 @@ def assemble_qt5_compressed(index, queries, L, Kn=3, Ks=3, doc_shards=1,
                 epos[qi, ki] = ep
         idf_sum[qi] = sum(lex.idf(l) for l in q)
     stub = QT5Batch(None, None, ns_r, None, None, st_r, idf_sum, span_adj, stride)
-    tail = (jnp.asarray(ns_r), jnp.asarray(cnt8), jnp.asarray(eneg),
-            jnp.asarray(epos), jnp.asarray(st_r),
-            jnp.asarray(idf_sum), jnp.asarray(span_adj))
+    tail = (ns_r, cnt8, eneg, epos, st_r, idf_sum, span_adj)
     if delta_fmt:
         nb = L // BLK
         a_base = np.zeros((B, nb), np.int32)
@@ -1446,9 +1463,7 @@ def assemble_qt5_compressed(index, queries, L, Kn=3, Ks=3, doc_shards=1,
                 if pr2:
                     ns_base[qi, ki] = b2
                     ns_delta[qi, ki] = d2
-        args = (jnp.asarray(a_base), jnp.asarray(a_delta), jnp.asarray(a_pad),
-                jnp.asarray(ns_base), jnp.asarray(ns_delta),
-                jnp.asarray(ns_pad)) + tail
+        args = (a_base, a_delta, a_pad, ns_base, ns_delta, ns_pad) + tail
         return "qt5_delta", args, stub
 
     def raw_row(lemma):
@@ -1468,7 +1483,7 @@ def assemble_qt5_compressed(index, queries, L, Kn=3, Ks=3, doc_shards=1,
                 g_row, pres = raw_row(lemma)
                 if pres:
                     ns_g[qi, ki] = g_row
-    return "qt5_offsets", (jnp.asarray(a_g), jnp.asarray(ns_g)) + tail, stub
+    return "qt5_offsets", (a_g, ns_g) + tail, stub
 
 
 def decode_results(batch: QT1Batch, top_s, top_g, top_lo, top_hi):

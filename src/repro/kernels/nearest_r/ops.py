@@ -112,30 +112,33 @@ def window_join(a_g, ns_g, ns_r, st_cnt=None, st_ext=None, st_r=None, *,
     """
     if use_pallas:
         from repro.kernels.nearest_r.nearest_r import window_join_pallas
-        return window_join_pallas(
-            a_g, ns_g, ns_r, st_cnt, st_ext, st_r,
-            max_sep=max_sep, r_max=r_max, interpret=interpret,
-            block_l=block_l, block_k=block_k, k_tiles=k_tiles)
+        with jax.named_scope("nearest_r"):
+            return window_join_pallas(
+                a_g, ns_g, ns_r, st_cnt, st_ext, st_r,
+                max_sep=max_sep, r_max=r_max, interpret=interpret,
+                block_l=block_l, block_k=block_k, k_tiles=k_tiles)
 
     B, Kn, L = ns_g.shape
     valid = a_g != SENTINEL
     lo = a_g
     hi = a_g
     if Kn:
-        b_flat = ns_g.reshape(B * Kn, L)
-        c_flat = jnp.broadcast_to(a_g[:, None, :], (B, Kn, L)).reshape(B * Kn, L)
-        r_flat = ns_r.reshape(B * Kn)
-        m, mn, mx = _nearest_r_counting(b_flat, c_flat, max_sep, r_flat, r_max)
-        m = m.reshape(B, Kn, L)
-        mn = mn.reshape(B, Kn, L)
-        mx = mx.reshape(B, Kn, L)
-        active = (ns_r > 0)[:, :, None]
-        valid &= jnp.all(m | ~active, axis=1)
-        upd = active & m
-        lo = jnp.minimum(lo, jnp.where(upd, mn, lo[:, None, :]).min(axis=1))
-        hi = jnp.maximum(hi, jnp.where(upd, mx, hi[:, None, :]).max(axis=1))
+        with jax.named_scope("nearest_r"):
+            b_flat = ns_g.reshape(B * Kn, L)
+            c_flat = jnp.broadcast_to(a_g[:, None, :], (B, Kn, L)).reshape(B * Kn, L)
+            r_flat = ns_r.reshape(B * Kn)
+            m, mn, mx = _nearest_r_counting(b_flat, c_flat, max_sep, r_flat, r_max)
+            m = m.reshape(B, Kn, L)
+            mn = mn.reshape(B, Kn, L)
+            mx = mx.reshape(B, Kn, L)
+            active = (ns_r > 0)[:, :, None]
+            valid &= jnp.all(m | ~active, axis=1)
+            upd = active & m
+            lo = jnp.minimum(lo, jnp.where(upd, mn, lo[:, None, :]).min(axis=1))
+            hi = jnp.maximum(hi, jnp.where(upd, mx, hi[:, None, :]).max(axis=1))
     if st_cnt is not None:
-        valid, lo, hi = _fold_stops(valid, lo, hi, a_g, st_cnt, st_ext, st_r)
+        with jax.named_scope("stops"):
+            valid, lo, hi = _fold_stops(valid, lo, hi, a_g, st_cnt, st_ext, st_r)
     return valid, lo, hi
 
 

@@ -16,9 +16,10 @@ the reproduction observes whether — and *where* — a budget is spent:
   (``SearchService.trace_snapshot()`` / ``write_trace()``,
   ``launch/serve.py --trace-out``).
 
-The package is dependency-free (numpy only) and serving-agnostic: the
-instruments know nothing about query types, so the index build path or
-the LM batcher can adopt the same registry later.
+The package is serving-agnostic: the instruments know nothing about
+query types, so the index build path or the LM batcher can adopt the
+same registry later. An enabled tracer's spans also land in a running
+JAX profile.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry  # noqa: F401

@@ -5,17 +5,24 @@ A :class:`Tracer` records *complete spans*: named intervals with a
 start timestamp, a duration, a thread id, a depth, and free-form args.
 Spans nest via a per-thread stack — ``with tracer.span("drain"):``
 makes every span opened inside it a child — so one
-``SearchService.drain()`` produces one span tree per drained batch:
+``SearchService.drain()`` produces one span tree, and its children
+name every part of the drain:
 
     drain
     ├─ plan
     ├─ group
-    └─ batch(qt1, B=16, L=1024)
-       ├─ pack
-       ├─ compress
-       ├─ dispatch
-       ├─ execute          (args: compile=True on the first (kind,B,L))
-       └─ decode
+    ├─ schedule            (work horizon + EDF group splitting)
+    ├─ batch(qt1, B=16, L=1024)      one per padded batch
+    │  ├─ pack
+    │  │  └─ transfer      (args: bytes; host->device copy calls)
+    │  ├─ compress         (cache-less compressed path; holds its transfer)
+    │  ├─ compile          (first (kind, B, L) only)
+    │  ├─ dispatch
+    │  ├─ execute          (args: compile=True on the first (kind,B,L))
+    │  └─ decode
+    │     └─ fetch         (device->host copy of the step's outputs)
+    ├─ respond             one per (family, bucket) unit: resolve tickets
+    └─ stats               (_finish_stats)
 
 Timestamps come from ``time.perf_counter()`` rebased to the tracer's
 creation (so they are small, strictly monotonic per thread, and share
@@ -26,10 +33,16 @@ microsecond ``ts``/``dur`` — which https://ui.perfetto.dev and
 containment per track, which is exactly the invariant the span stack
 enforces (tests/test_obs.py pins it).
 
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` with
+the span's name and args around its body, so under a running JAX
+profiler every span lands on the profile's host plane, on the
+profiler's own clock, next to the device's executables. With no
+profiler running the annotation records nothing.
+
 The buffer is a bounded ring (default 8192 completed spans, oldest
 evicted first) so a long-lived service cannot grow without bound;
-``enabled=False`` turns ``span()`` into a no-op context manager whose
-overhead is one attribute read.
+``enabled=False`` makes ``span()`` return one shared no-op context
+manager, which records nothing and allocates nothing.
 """
 
 from __future__ import annotations
@@ -37,9 +50,10 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
 from collections import deque
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "chrome_trace", "write_chrome_trace"]
 
@@ -65,10 +79,16 @@ class Span:
 
 
 class _NullSpan:
-    """The disabled-tracer span handle: accepts arg updates, keeps
-    nothing."""
+    """The disabled tracer's span: one shared no-op context manager
+    whose handle accepts arg updates and keeps nothing."""
 
     __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
 
     def set(self, **kw) -> None:
         pass
@@ -78,17 +98,49 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Handle yielded by :meth:`Tracer.span` while the span is open —
-    lets the body attach args discovered mid-span (e.g. the payload
-    kind a compressed pack settled on)."""
+    """One open span of an enabled tracer. As a handle it lets the body
+    attach args discovered mid-span (e.g. the payload kind a compressed
+    pack settled on); the profiler annotation gets them too."""
 
-    __slots__ = ("args",)
+    __slots__ = ("tracer", "name", "cat", "args", "depth", "t0", "_note")
 
-    def __init__(self, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+        self.tracer = tracer
+        self.name = name
+        self.cat = cat
         self.args = args
+
+    def __enter__(self):
+        stack = self.tracer._stack()
+        self.depth = len(stack)
+        stack.append(self.name)
+        self._note = TraceAnnotation(self.name, **self.args)
+        self._note.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        """Records the span. Exceptions propagate; the span is still
+        recorded (with ``error=True``) so a trace of a failing drain
+        shows where it died."""
+        t1 = time.perf_counter()
+        if exc_type is not None:
+            self.args["error"] = True
+        self._note.__exit__(exc_type, exc, tb)
+        tr = self.tracer
+        tr._stack().pop()
+        sp = Span(name=self.name, cat=self.cat, ts=self.t0 - tr.epoch,
+                  dur=t1 - self.t0, tid=threading.get_ident(),
+                  depth=self.depth, args=self.args)
+        with tr._lock:
+            if len(tr._spans) == tr.capacity:
+                tr._dropped += 1
+            tr._spans.append(sp)
+        return False
 
     def set(self, **kw) -> None:
         self.args.update(kw)
+        self._note.set_metadata(**kw)
 
 
 class Tracer:
@@ -112,33 +164,12 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    @contextmanager
     def span(self, name: str, cat: str = "serve", **args):
-        """Record ``name`` over the ``with`` body. Exceptions propagate;
-        the span is still recorded (with ``error=True``) so a trace of
-        a failing drain shows where it died."""
+        """A context manager that records ``name`` over the ``with``
+        body and yields a handle whose ``set(**kw)`` adds args."""
         if not self.enabled:
-            yield _NULL_SPAN
-            return
-        stack = self._stack()
-        depth = len(stack)
-        live = _LiveSpan(dict(args))
-        stack.append(name)
-        t0 = time.perf_counter()
-        try:
-            yield live
-        except BaseException:
-            live.args["error"] = True
-            raise
-        finally:
-            t1 = time.perf_counter()
-            stack.pop()
-            sp = Span(name=name, cat=cat, ts=t0 - self.epoch, dur=t1 - t0,
-                      tid=threading.get_ident(), depth=depth, args=live.args)
-            with self._lock:
-                if len(self._spans) == self.capacity:
-                    self._dropped += 1
-                self._spans.append(sp)
+            return _NULL_SPAN
+        return _LiveSpan(self, name, cat, args)
 
     # -- introspection -----------------------------------------------------
     def snapshot(self) -> list[Span]:

@@ -21,7 +21,9 @@ Two implementations of one :class:`Executor` protocol sit below the
 Observability contract (§15): both executors record into the service's
 shared :class:`repro.obs.MetricsRegistry` and :class:`repro.obs.Tracer`.
 Every batch emits a span tree (``batch`` → ``pack`` / ``compress`` /
-``compile`` / ``dispatch`` / ``execute`` / ``decode``) and every
+``compile`` / ``dispatch`` / ``execute`` / ``decode``; the host→device
+copy is a ``transfer`` span inside ``pack`` or ``compress``, the
+device→host copy a ``fetch`` span inside ``decode``) and every
 :class:`ExecResult` carries the same timings as a ``phases`` dict whose
 values tile ``[started_at, finished_at]`` exactly — the service adds
 queue/plan on top, which is how a response's phase breakdown sums to
@@ -42,6 +44,7 @@ from dataclasses import dataclass, field
 from typing import Protocol
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.jax_search import (
@@ -238,6 +241,8 @@ class CompiledExecutor:
             chunk_s = selections[lo:lo + cfg.max_batch]
             B_pad = batch_size_bucket(len(chunk_q), cfg.max_batch)
             pad = B_pad - len(chunk_q)
+            # the batch span also holds the batch's bookkeeping below,
+            # so a drain has no unnamed host time between its batches
             with self.tracer.span("batch", family=step_family, bucket=bucket,
                                   B=B_pad, n=len(chunk_q)) as bsp:
                 t0 = time.perf_counter()
@@ -257,55 +262,54 @@ class CompiledExecutor:
                     raw = jax.block_until_ready(raw)
                 t_exec = time.perf_counter()
                 with self.tracer.span("decode"):
+                    with self.tracer.span("fetch"):
+                        raw = tuple(np.asarray(r) for r in raw)
                     decoded = decode_results(stub, *raw)
                 t1 = time.perf_counter()
                 bsp.set(kind=kind, compile=first)
-            phases = {
-                "pack": t_pack - t0,
-                "compress": t_comp - t_pack,
-                "compile": t_compile - t_comp,
-                "dispatch": t_disp - t_compile,
-                "execute": t_exec - t_disp,
-                "decode": t1 - t_exec,
-            }
-            self.stats["batches"] += 1
-            if shared is not None and any(shared[lo:lo + cfg.max_batch]):
-                self.stats["shared_batches"] += 1
-            self.executables[key] = self.executables.get(key, 0) + 1
-            if not first:
-                # measured step cost = dispatch + device execute, run-only
-                self.metrics.observe(
-                    f"serve.step.{step_family}.B{B_pad}.L{bucket}",
-                    (t_exec - t_compile) * 1e6,
+                phases = {
+                    "pack": t_pack - t0,
+                    "compress": t_comp - t_pack,
+                    "compile": t_compile - t_comp,
+                    "dispatch": t_disp - t_compile,
+                    "execute": t_exec - t_disp,
+                    "decode": t1 - t_exec,
+                }
+                self.stats["batches"] += 1
+                if shared is not None and any(shared[lo:lo + cfg.max_batch]):
+                    self.stats["shared_batches"] += 1
+                self.executables[key] = self.executables.get(key, 0) + 1
+                if not first:
+                    self._observe_warm(step_family, B_pad, bucket, kind,
+                                       t_exec - t_compile,
+                                       (t1 - t0) - phases["compile"])
+                payload = _payload_of_kind(kind)
+                out.extend(
+                    ExecResult(results=decoded[bi], latency_s=t1 - t0,
+                               bucket=bucket, batch_size=len(chunk_q),
+                               payload=payload, started_at=t0, finished_at=t1,
+                               phases=dict(phases))
+                    for bi in range(len(chunk_q))
                 )
-                # whole warm batch wall-clock (host pack/compress/decode
-                # included, compile excluded): what one more batch of
-                # the shape actually costs the serving loop — the
-                # admission predictor's primitive; the step metric alone
-                # under-predicts it badly on host-bound small batches
-                self.metrics.observe(
-                    f"serve.batch.{step_family}.B{B_pad}.L{bucket}",
-                    ((t1 - t0) - phases["compile"]) * 1e6,
-                )
-                self.measured_keys.add((step_family, B_pad, bucket))
-                if self.costs is not None:
-                    # payload arbitration sees the whole warm batch cost
-                    # (pack/compress/decode included — host encode work
-                    # counts against the arm that incurs it), per padded
-                    # query; compile is excluded like the step metric
-                    warm_s = (t1 - t0) - phases["compile"]
-                    self.costs.observe(step_family, bucket,
-                                       _payload_of_kind(kind),
-                                       warm_s * 1e6 / B_pad)
-            payload = _payload_of_kind(kind)
-            out.extend(
-                ExecResult(results=decoded[bi], latency_s=t1 - t0,
-                           bucket=bucket, batch_size=len(chunk_q),
-                           payload=payload, started_at=t0, finished_at=t1,
-                           phases=dict(phases))
-                for bi in range(len(chunk_q))
-            )
         return out
+
+    def _observe_warm(self, family, B, L, kind, step_s, warm_s) -> None:
+        """Record one warm batch: its run-only step time (dispatch +
+        device execute) and its whole wall-clock, compile excluded."""
+        self.metrics.observe(f"serve.step.{family}.B{B}.L{L}", step_s * 1e6)
+        # whole warm batch wall-clock (host pack/compress/decode
+        # included): what one more batch of the shape actually costs
+        # the serving loop — the admission predictor's primitive; the
+        # step metric alone under-predicts it badly on host-bound
+        # small batches
+        self.metrics.observe(f"serve.batch.{family}.B{B}.L{L}", warm_s * 1e6)
+        self.measured_keys.add((family, B, L))
+        if self.costs is not None:
+            # payload arbitration sees the whole warm batch cost
+            # (pack/compress/decode included — host encode work counts
+            # against the arm that incurs it), per padded query
+            self.costs.observe(family, L, _payload_of_kind(kind),
+                               warm_s * 1e6 / B)
 
     # -- compile-vs-run split ----------------------------------------------
     def _executable_for(self, key, kind, max_distance, args):
@@ -470,11 +474,12 @@ class CompiledExecutor:
             # rows in one pass, so pack and compress are one phase here
             # (attributed to pack; compress reads 0)
             with self.tracer.span("pack", family=family, cached=True):
-                kind, args, stub = assemble_fn(
+                kind, host, stub = assemble_fn(
                     index, queries, L=bucket, doc_shards=cfg.doc_shards,
                     ccache=ccache, cache=self.pack_cache, plans=selections,
                     **kw,
                 )
+                args = self._transfer(host)
             self._count_compressed(kind)
             t_pack = time.perf_counter()
             return kind, stub, args, t_pack, t_pack
@@ -487,7 +492,7 @@ class CompiledExecutor:
                 )
                 # the host->device transfer of the packed rows belongs
                 # to pack, not to whatever phase is timed next
-                args = batch.device_args()
+                args = self._transfer(batch.host_args())
             t_pack = time.perf_counter()
             return kind, batch, args, t_pack, t_pack
         with self.tracer.span("pack", family=family):
@@ -497,9 +502,22 @@ class CompiledExecutor:
             )
         t_pack = time.perf_counter()
         with self.tracer.span("compress", family=family):
-            kind, args = self._compress_batch(bucket, batch, compress_fn,
+            kind, host = self._compress_batch(bucket, batch, compress_fn,
                                               prefix)
+            args = self._transfer(host)
         return kind, batch, args, t_pack, time.perf_counter()
+
+    def _transfer(self, host_args) -> tuple:
+        """Copy one batch's step arguments to the device (the
+        ``transfer`` span), counting the bytes sent in
+        ``serve.transfer.h2d_bytes``. The span times the host's calls;
+        what the runtime finishes after they return lands in
+        ``execute``."""
+        nbytes = sum(a.nbytes for a in host_args)
+        with self.tracer.span("transfer", bytes=nbytes):
+            args = tuple(jnp.asarray(a) for a in host_args)
+        self.metrics.inc("serve.transfer.h2d_bytes", nbytes)
+        return args
 
     def _compress_batch(self, bucket, batch, compress_fn, prefix=""):
         """Cache-less compressed path: whole-batch re-encode with the

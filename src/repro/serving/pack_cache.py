@@ -53,7 +53,6 @@ hits vs row derivations.
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 
 import numpy as np
@@ -188,12 +187,9 @@ class PackedPostingCache:
             self._counts["misses"] += 1
         # derive outside the lock: merged segment reads can be slow and
         # must not serialize concurrent serving threads
-        t_derive = time.perf_counter()
         rows = self._derive(index, kind, key, L, doc_shards, stride)
         if self._metrics is not None:
             self._metrics.inc(f"{self._scope}.misses")
-            self._metrics.observe(f"{self._scope}.derive_us",
-                                  (time.perf_counter() - t_derive) * 1e6)
             self._metrics.set(f"{self._scope}.bytes", self._bytes)
         if not rows[-1]:  # not present
             # negative entry: callers never read non-present rows, so they

@@ -778,6 +778,12 @@ class SearchService:
         list."""
         if not self._queue:
             return []
+        # the drain span covers the whole body: its children (plan,
+        # group, schedule, batch, respond, stats) name every part of it
+        with self.tracer.span("drain") as dsp:
+            return self._drain(dsp)
+
+    def _drain(self, dsp) -> list[SearchResponse]:
         index = self.index
         # swap the queue out under the submit lock BEFORE grouping: a
         # submit() racing this drain either lands before the swap (and
@@ -793,98 +799,54 @@ class SearchService:
             pending = self._drop_expired(pending)
             if not pending:
                 return []
-        t_drain0 = time.perf_counter()
+        dsp.set(requests=len(pending))
         slots: list = [None] * len(pending)
-        with self.tracer.span("drain", requests=len(pending)):
-            # per-request planning time is part of the phase breakdown
-            # (memoized hits are sub-µs; misses scan posting counts)
-            plans, plan_s = [], []
-            with self.tracer.span("plan", n=len(pending)):
-                for t in pending:
-                    tp0 = time.perf_counter()
-                    p = self._plan(index, t.lemma_ids)
-                    # a degraded admit reroutes to the cheaper bucket
-                    # here, against *this* drain's pinned snapshot (the
-                    # memoized plan stays untouched for other requests)
-                    if (t.degraded_bucket is not None and p.is_compiled
-                            and t.degraded_bucket < p.bucket):
-                        p = _planner.degrade(p, t.degraded_bucket,
-                                             self.config,
-                                             costs=self.payload_costs)
-                    plans.append(p)
-                    plan_s.append(time.perf_counter() - tp0)
-            with self.tracer.span("group"):
-                groups: dict[tuple, list[int]] = {}
-                for i, p in enumerate(plans):
-                    if p.route == _planner.ROUTE_EMPTY:
-                        key = ("empty", None)
-                    elif p.route == _planner.ROUTE_SCALAR:
-                        key = ("scalar", None)
-                    else:
-                        key = (p.step_family, p.bucket)
-                    groups.setdefault(key, []).append(i)
-
-                def urgency(item):
-                    _, idxs = item
-                    deadline = min(
-                        (pending[i].arrival + pending[i].deadline_s
-                         for i in idxs if pending[i].deadline_s is not None),
-                        default=float("inf"),
-                    )
-                    return (deadline, -len(idxs))
-
-                order = sorted(groups.items(), key=urgency)
-
-            # publish the drain's predicted work horizon: submits racing
-            # this drain see it as in-flight backlog (the queue itself
-            # was swapped empty above)
-            mb = self.config.max_batch
-            now0 = time.perf_counter()
-            horizon = 0.0
-            for (family, bucket), idxs in order:
-                if family == "empty":
-                    continue
-                if family == "scalar":
-                    horizon += len(idxs) * self.predictor.scalar_s()
+        # per-request planning time is part of the phase breakdown
+        # (memoized hits are sub-µs; misses scan posting counts)
+        plans, plan_s = [], []
+        with self.tracer.span("plan", n=len(pending)):
+            for t in pending:
+                tp0 = time.perf_counter()
+                p = self._plan(index, t.lemma_ids)
+                # a degraded admit reroutes to the cheaper bucket
+                # here, against *this* drain's pinned snapshot (the
+                # memoized plan stays untouched for other requests)
+                if (t.degraded_bucket is not None and p.is_compiled
+                        and t.degraded_bucket < p.bucket):
+                    p = _planner.degrade(p, t.degraded_bucket,
+                                         self.config,
+                                         costs=self.payload_costs)
+                plans.append(p)
+                plan_s.append(time.perf_counter() - tp0)
+        with self.tracer.span("group"):
+            groups: dict[tuple, list[int]] = {}
+            for i, p in enumerate(plans):
+                if p.route == _planner.ROUTE_EMPTY:
+                    key = ("empty", None)
+                elif p.route == _planner.ROUTE_SCALAR:
+                    key = ("scalar", None)
                 else:
-                    Bg = batch_size_bucket(min(len(idxs), mb), mb)
-                    horizon += (-(-len(idxs) // mb)) * self.predictor.batch_s(
-                        family, Bg, bucket)
-            self._inflight_until = now0 + horizon
+                    key = (p.step_family, p.bucket)
+                groups.setdefault(key, []).append(i)
 
-            # EDF group splitting (§17): when a tail ticket's budget
-            # cannot survive its whole group, peel an urgent sub-batch
-            # off at a smaller B-bucket — bounded by split_budget extra
-            # dispatches per drain
-            units: list[tuple[tuple, list[int]]] = []
-            splits_left = self.config.split_budget
-            t_acc = 0.0
-            for (family, bucket), idxs in order:
-                split = None
-                if family not in ("empty", "scalar") and splits_left > 0:
-                    split = self._split_urgent(pending, idxs, family,
-                                               bucket, t_acc, now0)
-                if split is not None:
-                    urgent, rest = split
-                    splits_left -= 1
-                    self.metrics.inc("serve.admission.split")
-                    with self._stats_lock:
-                        if "admission" in self.stats:
-                            self.stats["admission"]["splits"] += 1
-                    units.append(((family, bucket), urgent))
-                    units.append(((family, bucket), rest))
-                else:
-                    units.append(((family, bucket), idxs))
-                if family == "scalar":
-                    t_acc += len(idxs) * self.predictor.scalar_s()
-                elif family != "empty":
-                    Bg = batch_size_bucket(min(len(idxs), mb), mb)
-                    t_acc += (-(-len(idxs) // mb)) * self.predictor.batch_s(
-                        family, Bg, bucket)
+            def urgency(item):
+                _, idxs = item
+                deadline = min(
+                    (pending[i].arrival + pending[i].deadline_s
+                     for i in idxs if pending[i].deadline_s is not None),
+                    default=float("inf"),
+                )
+                return (deadline, -len(idxs))
 
-            for (family, bucket), idxs in units:
-                if family == "empty":
-                    now = time.perf_counter()
+            order = sorted(groups.items(), key=urgency)
+
+        with self.tracer.span("schedule"):
+            units = self._schedule(pending, order)
+
+        for (family, bucket), idxs in units:
+            if family == "empty":
+                now = time.perf_counter()
+                with self.tracer.span("respond", family=family, n=len(idxs)):
                     for i in idxs:
                         self._resolve(
                             pending[i], plans[i], slots, i,
@@ -893,37 +855,86 @@ class SearchService:
                                        finished_at=now),
                             plan_s[i],
                         )
-                    continue
-                queries = [pending[i].lemma_ids for i in idxs]
-                if family == "scalar":
-                    execs = self.scalar.execute(index, queries,
-                                                [None] * len(idxs),
-                                                step_family=None, bucket=None)
-                else:
-                    sels = [self._selection_for(plans[i], family) for i in idxs]
-                    shared = [plans[i].route != family for i in idxs]
-                    # one payload per (family, bucket) group: all its
-                    # plans were routed under the same cost-model state
-                    execs = self.compiled.execute(index, queries, sels,
-                                                  step_family=family,
-                                                  bucket=bucket, shared=shared,
-                                                  payload=plans[idxs[0]].payload)
-                    if bucket in self.stats["bucket_hist"]:
-                        mb = self.config.max_batch
-                        with self._stats_lock:
-                            self.stats["bucket_hist"][bucket] += (
-                                -(-len(idxs) // mb)
-                            )
+                continue
+            queries = [pending[i].lemma_ids for i in idxs]
+            if family == "scalar":
+                execs = self.scalar.execute(index, queries,
+                                            [None] * len(idxs),
+                                            step_family=None, bucket=None)
+            else:
+                sels = [self._selection_for(plans[i], family) for i in idxs]
+                shared = [plans[i].route != family for i in idxs]
+                # one payload per (family, bucket) group: all its
+                # plans were routed under the same cost-model state
+                execs = self.compiled.execute(index, queries, sels,
+                                              step_family=family,
+                                              bucket=bucket, shared=shared,
+                                              payload=plans[idxs[0]].payload)
+                if bucket in self.stats["bucket_hist"]:
+                    mb = self.config.max_batch
+                    with self._stats_lock:
+                        self.stats["bucket_hist"][bucket] += (
+                            -(-len(idxs) // mb)
+                        )
+            with self.tracer.span("respond", family=family, n=len(idxs)):
                 for i, ex in zip(idxs, execs):
                     self._resolve(pending[i], plans[i], slots, i, ex,
                                   plan_s[i])
         self._inflight_until = 0.0
-        self.metrics.observe(
-            "serve.drain.total",
-            (time.perf_counter() - t_drain0) * 1e6,
-        )
-        self._finish_stats(plans)
+        with self.tracer.span("stats"):
+            self._finish_stats(plans)
         return slots
+
+    def _schedule(self, pending, order) -> list[tuple[tuple, list[int]]]:
+        """The drain's units of work, ``((family, bucket), request
+        indices)`` in EDF order, after publishing its work horizon."""
+        # publish the drain's predicted work horizon: submits racing
+        # this drain see it as in-flight backlog (the queue itself was
+        # swapped empty at the drain's start)
+        mb = self.config.max_batch
+        now0 = time.perf_counter()
+        horizon = 0.0
+        for (family, bucket), idxs in order:
+            if family == "empty":
+                continue
+            if family == "scalar":
+                horizon += len(idxs) * self.predictor.scalar_s()
+            else:
+                Bg = batch_size_bucket(min(len(idxs), mb), mb)
+                horizon += (-(-len(idxs) // mb)) * self.predictor.batch_s(
+                    family, Bg, bucket)
+        self._inflight_until = now0 + horizon
+
+        # EDF group splitting (§17): when a tail ticket's budget
+        # cannot survive its whole group, peel an urgent sub-batch
+        # off at a smaller B-bucket — bounded by split_budget extra
+        # dispatches per drain
+        units: list[tuple[tuple, list[int]]] = []
+        splits_left = self.config.split_budget
+        t_acc = 0.0
+        for (family, bucket), idxs in order:
+            split = None
+            if family not in ("empty", "scalar") and splits_left > 0:
+                split = self._split_urgent(pending, idxs, family,
+                                           bucket, t_acc, now0)
+            if split is not None:
+                urgent, rest = split
+                splits_left -= 1
+                self.metrics.inc("serve.admission.split")
+                with self._stats_lock:
+                    if "admission" in self.stats:
+                        self.stats["admission"]["splits"] += 1
+                units.append(((family, bucket), urgent))
+                units.append(((family, bucket), rest))
+            else:
+                units.append(((family, bucket), idxs))
+            if family == "scalar":
+                t_acc += len(idxs) * self.predictor.scalar_s()
+            elif family != "empty":
+                Bg = batch_size_bucket(min(len(idxs), mb), mb)
+                t_acc += (-(-len(idxs) // mb)) * self.predictor.batch_s(
+                    family, Bg, bucket)
+        return units
 
     def _drop_expired(self, pending: list) -> list:
         """Shed requests whose deadline has already passed before any
@@ -1043,7 +1054,6 @@ class SearchService:
         m = self.metrics
         for name, dur in phases.items():
             m.observe(f"serve.phase.{name}", dur * 1e6)
-        m.observe("serve.request.e2e", e2e * 1e6)
         if blame is not None:
             m.inc(f"serve.deadline.miss_blame.{blame}")
         # §19 feedback loops: realized predicted-vs-actual completion
@@ -1143,9 +1153,10 @@ class SearchService:
         ``json.dump`` it and load the file in https://ui.perfetto.dev
         (or pass ``--trace-out`` to ``launch/serve.py`` /
         ``examples/serve_search.py``). One span tree per drain:
-        ``drain`` → ``plan`` / ``group`` / per-batch ``batch`` →
-        ``pack``/``compress``/``compile``/``dispatch``/``execute``/
-        ``decode``."""
+        ``drain`` → ``plan`` / ``group`` / ``schedule`` / per-batch
+        ``batch`` → ``pack`` (→ ``transfer``) / ``compress`` /
+        ``compile`` / ``dispatch`` / ``execute`` / ``decode`` (→
+        ``fetch``) / per-unit ``respond`` / ``stats``."""
         return chrome_trace(self.tracer.snapshot())
 
     def write_trace(self, path: str) -> dict:
