@@ -9,9 +9,10 @@ Key re-design vs the CPU engine (DESIGN.md §3):
   three-component-key posting lists of bucketed length L (padding =
   SENTINEL). K and L are *static* — the compiled step is the response-time
   guarantee;
-* Equalize == sorted intersection: key list 0 is the anchor stream; lists
-  1..K-1 are joined via vectorized searchsorted membership on every
-  backend (the QT1 step has no Pallas path);
+* Equalize == sorted intersection: key list 0 is the anchor stream; one
+  sort along the lanes merges all K sorted lists into one row (no search
+  loop, no gather), where a posting joins iff the K-1 lanes before it
+  hold its key (the QT1 step has no Pallas path);
 * the index is document-sharded over the `model` mesh axis (each shard
   holds a doc range of every posting list); queries are batch-sharded over
   `pod`/`data`. Per-shard top-k results are all-gathered (k entries per
@@ -39,37 +40,44 @@ NEG_INF = jnp.float32(-1e30)
 # --------------------------------------------------------------------------
 # batched single-device primitives
 # --------------------------------------------------------------------------
-def _membership(g0: jnp.ndarray, gk: jnp.ndarray):
-    """Batched membership of g0 rows in gk rows: (B, L) int32 each."""
-
-    def one(g0_row, gk_row):
-        idx = jnp.searchsorted(gk_row, g0_row)
-        idx_c = jnp.clip(idx, 0, gk_row.shape[0] - 1)
-        found = (gk_row[idx_c] == g0_row) & (g0_row != SENTINEL)
-        return found, idx_c
-
-    return jax.vmap(one)(g0, gk)
+def _shift_right(x, s: int, fill):
+    """(B, W) rows moved s lanes right; the first s lanes hold ``fill``."""
+    return jnp.pad(x[:, :-s], ((0, 0), (s, 0)), constant_values=fill)
 
 
 def qt1_join(key_g: jnp.ndarray, key_lo: jnp.ndarray, key_hi: jnp.ndarray):
-    """Join K key posting lists on the anchor stream (list 0).
+    """Join K key posting lists on the anchor stream (list 0): the
+    intersection of sorted rows, as one merge.
 
-    key_g/lo/hi: (B, K, L) int32. Returns (valid, lo, hi) each (B, L),
-    aligned with the anchor list."""
-    K = key_g.shape[1]
+    Each row is strictly increasing (the index keeps one posting per
+    (f,s,t,doc,pos), so a g occurs at most once in a row) and
+    SENTINEL-padded. One sort of the K rows' lanes on g merges them:
+    a g that every row holds fills K adjacent lanes, one from each row;
+    the last of them is the joined lane, and its fragment spans all K.
+    A window of K lanes holds at most one joined lane, so for K > 1 each
+    pair of lanes folds into one before scoring.
+
+    key_g/lo/hi: (B, K, L) int32. Returns (g, valid, lo, hi), each (B, W):
+    W = K*L, halved when K > 1 and K*L is even (L itself at K = 2). The joined
+    lanes keep the anchor list's order (increasing g), so top_k breaks
+    ties among them as it did on the anchor list."""
+    B, K, L = key_g.shape
     with jax.named_scope("membership"):
-        g0 = key_g[:, 0]
-        valid = g0 != SENTINEL
-        lo = key_lo[:, 0]
-        hi = key_hi[:, 0]
-        for k in range(1, K):
-            found, idx = _membership(g0, key_g[:, k])
-            valid &= found
-            lo_k = jnp.take_along_axis(key_lo[:, k], idx, axis=1)
-            hi_k = jnp.take_along_axis(key_hi[:, k], idx, axis=1)
-            lo = jnp.where(found, jnp.minimum(lo, lo_k), lo)
-            hi = jnp.where(found, jnp.maximum(hi, hi_k), hi)
-    return valid, lo, hi
+        g, lo, hi = jax.lax.sort(tuple(x.reshape(B, K * L) for x in (key_g, key_lo, key_hi)),
+                                 dimension=1, num_keys=1)
+        valid = g != SENTINEL
+        lo_k, hi_k = lo, hi
+        for s in range(1, K):
+            valid &= _shift_right(g, s, SENTINEL) == g
+            lo = jnp.minimum(lo, _shift_right(lo_k, s, SENTINEL))
+            hi = jnp.maximum(hi, _shift_right(hi_k, s, -SENTINEL))
+        if K > 1 and K * L % 2 == 0:
+            def fold(x):
+                return jnp.where(valid[:, 1::2], x[:, 1::2], x[:, 0::2])
+
+            g, lo, hi = fold(g), fold(lo), fold(hi)
+            valid = valid[:, 1::2] | valid[:, 0::2]
+    return g, valid, lo, hi
 
 
 def qt1_score(valid, lo, hi, idf_sum, span_adjust):
@@ -252,8 +260,8 @@ def _sharded_topk(score, g, lo, hi, top_k: int):
 
 
 def make_qt1_serve_step(mesh, top_k: int = 16):
-    """Build the jitted, mesh-sharded QT1 serve step (lax searchsorted
-    membership join; no Pallas kernel).
+    """Build the jitted, mesh-sharded QT1 serve step (the merge join of
+    :func:`qt1_join`; no Pallas kernel).
 
     Sharding: batch over pod+data axes, posting length (doc ranges) over
     model. The all-gather moves only K' = top_k entries per shard."""
@@ -261,9 +269,9 @@ def make_qt1_serve_step(mesh, top_k: int = 16):
     batch_axes = ("pod", "data") if has_pod else ("data",)
 
     def local_step(key_g, key_lo, key_hi, idf_sum, span_adjust):
-        valid, lo, hi = qt1_join(key_g, key_lo, key_hi)
+        g, valid, lo, hi = qt1_join(key_g, key_lo, key_hi)
         score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-        return _sharded_topk(score, key_g[:, 0], lo, hi, top_k)
+        return _sharded_topk(score, g, lo, hi, top_k)
 
     batch_spec = P(batch_axes, None, "model")
     vec_spec = P(batch_axes)
@@ -317,9 +325,9 @@ def make_qt1_serve_step_compressed(mesh, top_k: int = 16, delta_g: bool = True):
             # SENTINEL-preservation: padding slots are marked by lo_off==255
             pad = key_lo_off == 255
             key_g = jnp.where(pad, SENTINEL, key_g)
-        valid, lo, hi = qt1_join(key_g, lo, hi)
+        g, valid, lo, hi = qt1_join(key_g, lo, hi)
         score = qt1_score(valid, lo, hi, idf_sum, span_adjust)
-        return _sharded_topk(score, key_g[:, 0], lo, hi, top_k)
+        return _sharded_topk(score, g, lo, hi, top_k)
 
     batch_spec = P(batch_axes, None, "model")
     # offsets-only: the dummy (B,K,1) base cannot shard its unit dim
@@ -780,8 +788,8 @@ def pack_qt1_batch(
 
     INVARIANT: doc_shards must equal the serving mesh's model-axis size.
     Each range-partitioned segment is sorted *locally*; the concatenated
-    row is not globally sorted, so the searchsorted-based join is only
-    correct when each model shard sees exactly one segment."""
+    row is not globally sorted, so the merge join is only correct when
+    each model shard sees exactly one segment."""
     B = len(queries)
     lex = index.lexicon
     stride = qt1_stride(index)

@@ -93,14 +93,17 @@ def _compile(step, args):
 def test_qt1_raw_step_compiles_for_v5e(one_chip_mesh, batches):
     batch = batches["qt1"]
     assert batch.key_g.shape == (B, 2, L)
-    _compile(make_qt1_serve_step(one_chip_mesh, top_k=16), batch.device_args())
+    hlo = _compile(make_qt1_serve_step(one_chip_mesh, top_k=16),
+                   batch.device_args()).as_text()
+    # the merge join is straight-line: no search loop left on the chip
+    assert "while" not in hlo
 
 
 def test_qt1_delta16_step_compiles_for_v5e(one_chip_mesh, batches):
     args = compress_qt1_batch(batches["qt1"], delta_g=True)
     step = make_qt1_serve_step_compressed(one_chip_mesh, top_k=16,
                                           delta_g=True)
-    _compile(step, args)
+    assert "while" not in _compile(step, args).as_text()
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
