@@ -13,10 +13,17 @@ are on ``perf_counter``, so through the annotation every device event
 can be put inside the batch that launched it and every idle gap beside
 what the host was doing.
 
-* busy: the union of the intervals in which an operation ran, inside
-  the window, averaged over the devices;
+On n devices (``n_devices``, the trace's TPU planes):
+
+* busy: on each device the union of the intervals in which an
+  operation ran inside the window; ``busy_s`` is their mean, one
+  device's busy time;
 * per family: the device seconds of the executables each family's
-  batches ran, and the (B, L) of each run, for the kernels' work count;
+  batches ran, as their mean over the devices (one device's time), and
+  the (B, L) of each batch once, however many devices ran it, for the
+  kernels' work count. A roofline reader divides a batch's whole work by
+  n times one device's peak, so it reads a share of the n devices'
+  combined peak;
 * ``top_ops``: device operations by total time (``family/op``);
 * ``top_gaps``: device-idle time inside the window, summed by what the
   host was doing (``drain/pack``, ``drain/decode``, ``wait`` ...).
@@ -26,6 +33,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 from dataclasses import dataclass, field
 
@@ -36,8 +44,9 @@ WINDOW = "bench.window"
 class DeviceTrace:
     busy_s: float
     window_s: float
+    n_devices: int = 1
     family_s: dict = field(default_factory=dict)      # family -> device seconds
-    family_runs: dict = field(default_factory=dict)   # family -> [(B, L), ...]
+    family_runs: dict = field(default_factory=dict)   # family -> [(B, L)], a batch once
     top_ops: list = field(default_factory=list)       # [[name, seconds], ...]
     top_gaps: list = field(default_factory=list)      # [[host state, seconds], ...]
     unplaced_runs: int = 0                            # module runs in no batch
@@ -76,30 +85,39 @@ def _op_name(event_name: str) -> str:
 
 class _Host:
     """What the host was doing at an instant (``perf_counter`` seconds):
-    the deepest service span covering it, under the loop's state."""
+    the deepest service span covering it, under the loop's state.
+
+    The nested spans and states are flattened once into a piecewise-
+    constant timeline of that label, so a lookup is one ``bisect``
+    however long the window's longest span (a stalled drain) is."""
 
     def __init__(self, spans, states):
         ivs = [(t0, t0 + dur, depth, name) for name, t0, dur, depth, _a in spans]
         ivs += [(t0, t1, -1, name) for name, t0, t1 in states]
-        self.ivs = sorted(ivs)
-        self.starts = [iv[0] for iv in self.ivs]
-        self.max_dur = max((b - a for a, b, _d, _n in self.ivs), default=0.0)
+        ivs.sort()
+        self.edges = sorted({x for a, b, _d, _n in ivs for x in (a, b)})
+        # the label of [edges[i], edges[i+1]): of the intervals covering
+        # it, the deepest, and of equally deep ones the last in ``ivs``
+        self.labels = []
+        heap, j = [], 0
+        for p in self.edges:
+            while j < len(ivs) and ivs[j][0] <= p:
+                heapq.heappush(heap, (-ivs[j][2], -j))
+                j += 1
+            while heap and ivs[-heap[0][1]][1] <= p:
+                heapq.heappop(heap)
+            self.labels.append(self._name(ivs[-heap[0][1]]) if heap else "outside")
+
+    @staticmethod
+    def _name(iv) -> str:
+        _a, _b, depth, name = iv
+        if depth < 0 or name == "drain":
+            return name
+        return f"drain/{name}"
 
     def label(self, t: float) -> str:
-        k = bisect.bisect_right(self.starts, t)
-        best = None
-        while k > 0:
-            k -= 1
-            a, b, depth, name = self.ivs[k]
-            if a < t - self.max_dur:
-                break
-            if a <= t < b and (best is None or depth > best[0]):
-                best = (depth, name)
-        if best is None:
-            return "outside"
-        if best[0] < 0:
-            return best[1]
-        return best[1] if best[1] == "drain" else f"drain/{best[1]}"
+        k = bisect.bisect_right(self.edges, t) - 1
+        return self.labels[k] if k >= 0 else "outside"
 
     def split(self, a: float, b: float, pieces: int = 8) -> dict:
         """Seconds of [a, b] by host label (label at each piece's middle)."""
@@ -138,6 +156,7 @@ def reduce_profile(pd, t_window: float, spans: list, states: list) -> DeviceTrac
     busy_total = 0.0
     family_s: dict = {}
     family_runs: dict = {}
+    runs_seen: set = set()  # (batch, ordinal on its plane): a run counted once
     op_s: dict = {}
     gap_s: dict = {}
     unplaced = 0
@@ -146,6 +165,7 @@ def reduce_profile(pd, t_window: float, spans: list, states: list) -> DeviceTrac
         modules = sorted((ev.start_ns, ev.start_ns + ev.duration_ns)
                          for ev in lines.get("XLA Modules", []))
         fam_of_module = []
+        runs_in_batch: dict = {}
         for a_ns, b_ns in modules:
             a, b = a_ns * 1e-9 + off, b_ns * 1e-9 + off
             k = bisect.bisect_right(b_starts, a) - 1
@@ -153,8 +173,12 @@ def reduce_profile(pd, t_window: float, spans: list, states: list) -> DeviceTrac
                 args = batches[k][2]
                 fam = str(args["family"])
                 family_s[fam] = family_s.get(fam, 0.0) + (b - a)
-                family_runs.setdefault(fam, []).append(
-                    (int(args["B"]), int(args["bucket"])))
+                key = (k, runs_in_batch.get(k, 0))
+                runs_in_batch[k] = key[1] + 1
+                if key not in runs_seen:  # else: the batch on another device
+                    runs_seen.add(key)
+                    family_runs.setdefault(fam, []).append(
+                        (int(args["B"]), int(args["bucket"])))
                 fam_of_module.append(fam)
             else:
                 unplaced += 1
@@ -183,7 +207,7 @@ def reduce_profile(pd, t_window: float, spans: list, states: list) -> DeviceTrac
     top_ops = sorted(([k, v / n] for k, v in op_s.items()), key=lambda x: -x[1])
     top_gaps = sorted(([k, v / n] for k, v in gap_s.items()), key=lambda x: -x[1])
     return DeviceTrace(
-        busy_s=busy_total / n, window_s=(w1_ns - w0_ns) * 1e-9,
+        busy_s=busy_total / n, window_s=(w1_ns - w0_ns) * 1e-9, n_devices=n,
         family_s={k: v / n for k, v in family_s.items()},
         family_runs=family_runs, top_ops=top_ops, top_gaps=top_gaps,
         unplaced_runs=unplaced)

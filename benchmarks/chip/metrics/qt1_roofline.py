@@ -1,6 +1,8 @@
 """Roofline share of the QT1 serve step: the least time its work could
-take at the chip's peaks (``kernels/qt1.py``: bytes over HBM bandwidth)
-over the device time of the QT1 executables' runs in the trace."""
+take at the cell's peaks (``kernels/qt1.py``: bytes over the HBM
+bandwidth of all the devices the trace holds) over the device time of
+the QT1 executables' runs in the trace (one device's, the mean over
+them)."""
 
 
 def read(run):
@@ -9,4 +11,5 @@ def read(run):
         return None
     work = run.kernel_work("qt1")
     nbytes = sum(work(B, L, run.serve)["bytes"] for B, L in dev.family_runs["qt1"])
-    return 100.0 * nbytes / run.peaks["hbm_bytes_per_s"] / dev.family_s["qt1"]
+    bandwidth = dev.n_devices * run.peaks["hbm_bytes_per_s"]
+    return 100.0 * nbytes / bandwidth / dev.family_s["qt1"]

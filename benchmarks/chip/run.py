@@ -25,6 +25,15 @@ The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` with
 ``--trace 1``), and last ``checks``: each number the correctness check
 compared, with its limit. The same numbers close stderr.
+
+``device`` holds what JAX reports (``platform``, ``kind``, ``count``),
+``n_devices``, the devices the cell uses, ``memory_peak_bytes``, the
+peak of the fullest of them, and ``memory_peak_bytes_per_device``, each
+one's peak. With ``--trace 1`` it adds ``busy_s``, one device's busy
+seconds (the mean over the cell's devices), and ``window_s``. The
+trace's per-family runs (``devtrace.DeviceTrace.family_runs``) count
+each batch once, however many devices ran it, and the roofline readers
+divide its work by the cell's devices times one device's peak.
 """
 
 from __future__ import annotations
@@ -375,8 +384,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if svc.tracer.dropped:
         log(f"tracer dropped {svc.tracer.dropped} spans of the window")
     in_window = svc.compiled.stats["compiles"] - compiles0
-    mem = devices[0].memory_stats() or {}
-    memory_peak = int(mem.get("peak_bytes_in_use", 0))
+    memory_peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                    for d in devices]
 
     # client-side latency: a response reaches its caller when the drain
     # that served it returns
@@ -413,7 +422,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     result_device = {"platform": devices[0].platform,
                      "kind": devices[0].device_kind,
                      "count": len(jax.devices()),
-                     "memory_peak_bytes": memory_peak}
+                     "n_devices": len(devices),
+                     "memory_peak_bytes": max(memory_peaks),
+                     "memory_peak_bytes_per_device": memory_peaks}
     # free the program's state before the reference runs
     del svc, rec
     gc.collect()
@@ -422,8 +433,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if profile:
         import devtrace
 
+        t = time.perf_counter()
         try:
             dt = devtrace.reduce_dir(trace_dir, t_anchor, spans, states)
+            log(f"trace reduction: {dt.n_devices} devices, "
+                f"{time.perf_counter() - t:.3f}s")
         finally:
             if keep_trace:
                 shutil.copytree(trace_dir, keep_trace, dirs_exist_ok=True)

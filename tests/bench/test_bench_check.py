@@ -5,10 +5,9 @@ control and for a timed path broken underneath."""
 import numpy as np
 import pytest
 
-from bench_tiny import SEED, cpu_devices, quiet, run, tiny
+from bench_tiny import FAULTS, SEED, cell_names, run, tiny, tiny_outcome
 
 import check  # noqa: E402  (the harness directory is on the path)
-import control  # noqa: E402
 import corpus as bench_corpus  # noqa: E402
 from reference import Reference, check_response  # noqa: E402
 
@@ -67,47 +66,21 @@ def test_samplers_match_program():
             == bench_corpus.sample_mixed_queries(c, 80, window=9, seed=SEED + 2))
 
 
-@pytest.mark.parametrize("cell_name", ["md5-frequent-poisson", "md9-mixed-closed"])
+@pytest.mark.parametrize("cell_name", cell_names())
 def test_control_is_not_correct(cell_name):
     """The reference at MaxDistance - 1 in the program's place fails the
     check that the program passes on the same responses."""
     cell = tiny(cell_name)
-    r = control.readings(cell, SEED, 1.0, cpu_devices(cell), log=quiet)
+    r = tiny_outcome(cell, False, mode="control")
     assert r["program_correct"] and r["program"]["mismatched"] == 0
     assert not r["control_correct"] and r["control"]["mismatched"] > 0
 
 
-def _altered_decode(real):
-    def decode(batch, *outs):
-        out = real(batch, *outs)
-        for res in out:
-            if res["doc"].size:
-                res["end"] = res["end"] + 1
-        return out
-    return decode
-
-
-def _half_batch(real):
-    def execute(self, index, queries, selections, **kw):
-        out = real(self, index, queries, selections, **kw)
-        for ex in out[len(out) // 2:]:
-            ex.results = {k: v[:0] for k, v in ex.results.items()}
-        return out
-    return execute
-
-
-@pytest.mark.parametrize("fault", ["answer_altered", "half_batch_left_out"])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
-    from repro.serving import executors
-
-    if fault == "answer_altered":
-        monkeypatch.setattr(executors, "decode_results",
-                            _altered_decode(executors.decode_results))
-    else:
-        monkeypatch.setattr(executors.CompiledExecutor, "execute",
-                            _half_batch(executors.CompiledExecutor.execute))
-    cell = tiny("md9-mixed-closed")
-    res = run.run_cell(cell, SEED, 1.0, False, platform="cpu", log=quiet)
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+@pytest.mark.parametrize("cell_name", cell_names())
+def test_broken_timed_path_is_not_correct(cell_name, fault, monkeypatch):
+    cell = tiny(cell_name)
+    res = tiny_outcome(cell, False, fault=fault, patch=monkeypatch.setattr)
     assert res["correct"] is False
     assert res["checks"]["mismatched"]["value"] > 0
 

@@ -4,9 +4,9 @@ nothing for them to read."""
 
 import pytest
 
-from bench_tiny import run
+from bench_tiny import cell_names, first_cell_of_loop, run
 
-CELLS = {"open": "md5-frequent-poisson", "closed": "md9-mixed-closed"}
+CELLS = {loop: first_cell_of_loop(loop) for loop in ("open", "closed")}
 
 # (name, start, duration, depth, args), as run.py hands spans to readers
 SPANS = [
@@ -75,9 +75,11 @@ def test_readers_none_for_a_program_without_them(loop):
         assert _read(f"{metric}.{loop}", data) is None
 
 
-def test_each_cell_lists_its_three_readers():
+@pytest.mark.parametrize("cell_name", cell_names())
+def test_each_cell_lists_its_three_readers(cell_name):
     bench = run.load_benchmark()
-    for loop, cell in CELLS.items():
-        names = {m["name"] for m in run.resolve_cell(bench, cell, True).metrics}
-        assert {f"transfer_us_per_query.{loop}", f"h2d_bytes_per_query.{loop}",
-                f"service_us_per_query.{loop}"} <= names
+    cell = run.resolve_cell(bench, cell_name, True)
+    loop = cell.traffic["arrivals"]["loop"]
+    names = {m["name"] for m in cell.metrics}
+    assert {f"transfer_us_per_query.{loop}", f"h2d_bytes_per_query.{loop}",
+            f"service_us_per_query.{loop}"} <= names

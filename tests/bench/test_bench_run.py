@@ -10,9 +10,10 @@ import sys
 
 import pytest
 
-from bench_tiny import HARNESS, ROOT, SEED, quiet, run, tiny
+from bench_tiny import (HARNESS, ROOT, SEED, cell_names, first_cell_of_loop, quiet,
+                        run, tiny, tiny_outcome)
 
-CELLS = ["md5-frequent-poisson", "md9-mixed-closed"]
+CELLS = cell_names()
 
 
 def _env():
@@ -50,11 +51,7 @@ def test_benchmark_files_alone_fail(tmp_path):
     _no_result(proc)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("cell_name", CELLS)
-def test_tiny_cell_end_to_end(cell_name, trace):
-    cell = tiny(cell_name, bool(trace))
-    res = run.run_cell(cell, SEED, 1.0, bool(trace), platform="cpu", log=quiet)
+def _assert_tiny_result(cell, res, trace):
     assert res["correct"] is True
     assert res["attempted"] > 0 and res["failed"] == 0
     assert list(res)[-1] == "checks"
@@ -71,18 +68,66 @@ def test_tiny_cell_end_to_end(cell_name, trace):
         assert got == want and "setup_s" in got
     for m in res["metrics"].values():
         assert m["value"] > 0
+    dev = res["device"]
+    assert dev["n_devices"] == cell.entry["chips"]
+    assert len(dev["memory_peak_bytes_per_device"]) == dev["n_devices"]
+    assert dev["memory_peak_bytes"] == max(dev["memory_peak_bytes_per_device"])
 
 
-def test_cells_report_setup_and_their_layers():
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_tiny_cell_end_to_end(cell_name, trace):
+    cell = tiny(cell_name, bool(trace))
+    _assert_tiny_result(cell, tiny_outcome(cell, bool(trace)), trace)
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_cells_report_setup_and_their_layers(cell_name):
+    """Every cell of BENCHMARK.json reports ``setup_s`` and another
+    end-to-end metric, each of its per-layer metrics moves one of them,
+    and every metric has its reader (the loop's own readers:
+    ``test_bench_drain_spans.test_each_cell_lists_its_three_readers``)."""
     bench = run.load_benchmark()
-    assert {w["name"] for w in run.load_benchmark()["workloads"]} <= set(CELLS)
-    for name in CELLS:
-        e2e = run.resolve_cell(bench, name, False).metrics
-        layer = run.resolve_cell(bench, name, True).metrics
-        assert "setup_s" in {m["name"] for m in e2e} and len(e2e) >= 2
-        assert layer and all(m["moves"] in {e["name"] for e in e2e} for m in layer)
-        for m in e2e + layer:
-            assert (HARNESS / "metrics" / f"{m['name']}.py").is_file()
+    e2e = run.resolve_cell(bench, cell_name, False).metrics
+    layer = run.resolve_cell(bench, cell_name, True).metrics
+    assert "setup_s" in {m["name"] for m in e2e} and len(e2e) >= 2
+    assert layer and all(m["moves"] in {e["name"] for e in e2e} for m in layer)
+    for m in e2e + layer:
+        assert (HARNESS / "metrics" / f"{m['name']}.py").is_file()
+
+
+def test_metrics_name_only_cells_that_exist():
+    bench = run.load_benchmark()
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", [])) <= set(CELLS), m["name"]
+
+
+def test_four_chip_cell_is_files_and_entries_only(tmp_path):
+    """A four-chip cell -- a configuration file with ``doc_shards`` 4, a
+    ``chips`` 4 workload and its names in the metrics' lists -- runs end
+    to end on four host CPU devices and is correct, with no edit to the
+    harness or its tests; with an answer altered it is not."""
+    bench = run.load_benchmark()
+    cfg = json.loads((HARNESS / "configs" / "fiction-md5.json").read_text())
+    cfg["name"] = "fiction-md5-4chip"
+    cfg["serve"]["doc_shards"] = 4
+    rel = "benchmarks/chip/configs/fiction-md5-4chip.json"
+    (tmp_path / rel).parent.mkdir(parents=True)
+    (tmp_path / rel).write_text(json.dumps(cfg))
+    bench["configs"].append({"name": "fiction-md5-4chip", "source": "x", "file": rel,
+                             "reduced": ["n_docs"], "why": "x"})
+    bench["workloads"].append({"name": "md5-frequent-4chip", "config": "fiction-md5-4chip",
+                               "traffic": "frequent-poisson", "chips": 4, "why": "x"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "md5-frequent-poisson" in m.get("workloads", []):
+            m["workloads"].append("md5-frequent-4chip")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = tiny("md5-frequent-4chip", False, root=tmp_path)
+    assert cell.config["serve"]["doc_shards"] == 4 and cell.entry["chips"] == 4
+    assert {"latency_p50_ms", "latency_p95_ms"} <= {m["name"] for m in cell.metrics}
+    _assert_tiny_result(cell, tiny_outcome(cell, False, root=tmp_path), 0)
+    broken = tiny_outcome(cell, False, root=tmp_path, fault="answer_altered")
+    assert broken["correct"] is False and broken["device"]["n_devices"] == 4
 
 
 def test_found_by_name(tmp_path):
@@ -134,7 +179,7 @@ def test_seed_changes_order_not_sizes():
 
     import load  # noqa: E402  (the harness directory is on the path)
 
-    cell = tiny(CELLS[0])
+    cell = tiny(first_cell_of_loop("open"))
     a, pool_a = run.generate(cell.config, cell.traffic, SEED)
     b, pool_b = run.generate(cell.config, cell.traffic, SEED + 1)
     a2, _ = run.generate(cell.config, cell.traffic, SEED)
